@@ -16,6 +16,7 @@ from benchmarks import (bench_ablation, bench_calibrate, bench_e2e,
                         bench_kv_transform, bench_overall_cost,
                         bench_scheduler, bench_tp_tradeoff,
                         bench_weights)
+from repro.launch.compile_cache import use_compile_cache
 
 MODULES = {
     "table1": bench_tp_tradeoff,
@@ -61,6 +62,7 @@ def main() -> None:
                     help="output path for --trajectory (default "
                          "BENCH_<date>.json in the working directory)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.trajectory:
         print(f"trajectory,{emit_trajectory(args.out)}")
         return

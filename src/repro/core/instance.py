@@ -42,14 +42,6 @@ from repro.paged.pool import PagedState
 REP, SP, TP = "rep", "sp", "tp"
 
 
-def mesh_context(mesh: Mesh):
-    """``jax.set_mesh`` appeared in newer jax; on 0.4.x a Mesh is itself
-    the context manager that scopes bare-PartitionSpec sharding."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 # ---------------------------------------------------------------------------
 # PartitionSpec trees (identical for every TP degree)
 # ---------------------------------------------------------------------------
@@ -256,7 +248,7 @@ class InstanceGroup:
             "scheduled transformation in progress: prefill would write "
             "into the stale stacked caches that finish_transform discards")
         cfg, plan = self.cfg, self.plan
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             logits, self.caches = M.prefill(self.params, cfg, plan, batch,
                                             self.caches)
         return logits
@@ -270,7 +262,7 @@ class InstanceGroup:
                 s.layers, s.static, self.cfg, self.plan, tokens,
                 positions, static_mesh=s.static_mesh)
             return logits
-        with mesh_context(self.mesh):
+        with jax.set_mesh(self.mesh):
             logits, self.caches = self._decode_fn()(
                 self.params, self.caches, tokens, positions)
         return logits

@@ -359,7 +359,6 @@ def _sharded_migration_jit(direction: str, mesh: jax.sharding.Mesh,
     the same geometry reuse one compiled collective instead of
     re-tracing per call (step timing then measures the migration, not
     the compile)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P_
 
     from repro.kernels import page_migrate as PM
@@ -420,8 +419,8 @@ def _sharded_migration_jit(direction: str, mesh: jax.sharding.Mesh,
 
         in_specs, out_specs = P_(None, axis), P_(axis)
 
-    return jax.jit(shard_map(per_worker, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(per_worker, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def migrate_scale_up_sharded(pool: jax.Array, mesh: jax.sharding.Mesh,

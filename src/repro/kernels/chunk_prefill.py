@@ -18,8 +18,8 @@ pool block is written back (unchanged on prefix steps), so the aliased
 output stays coherent; untouched pages are preserved by the aliasing.
 
 Preconditions (the engine's slot-partitioned pools satisfy all three;
-``chunk_prefill_eligible`` guards what it can check statically, callers
-fall back to the jnp path otherwise):
+``chunk_prefill_eligible`` guards what it can check statically, and the
+model treats a refusal as an error):
 
 * chunk boundaries are page boundaries: ``q_positions[:, 0]`` is a
   multiple of ``page_tokens`` (the PrefillPolicy invariant), so each
@@ -68,16 +68,18 @@ def _fused_kernel(
     pt_ref,        # (B, n_pages) int32 — the pool page table
     sp_ref,        # (B, NC) int32 — physical page of each chunk sub-block
     # inputs
-    q_ref,         # (1, Sp, Hq, dh)    all of the chunk's queries
-    qpos_ref,      # (1, Sp) int32      query positions (-1 = padding)
-    kvpos_ref,     # (1, 1, P) int32    pool slot positions of page j
-    cpos_ref,      # (1, 1, P) int32    chunk positions of sub-block c
+    q_ref,         # (1, kvs, M, dh)    the chunk's queries, grouped per
+                   #                    kv head (row m = token m // rep)
+    qpos_ref,      # (1, M, 1) int32    query position of each row
+    kvpos_ref,     # (1, 1, 1, P) int32 pool slot positions of page j
+    cpos_ref,      # (1, 1, 1, P) int32 chunk positions of sub-block c
+    ckeep_ref,     # (1, 1, P, 1) int32 the same positions, as a column
     knew_ref,      # (1, 1, kvs, P, dh) chunk K of sub-block c
     vnew_ref,      # (1, 1, kvs, P, dh) chunk V of sub-block c
     pool_ref,      # (1, kvs, 2, P, dh) one pool page (aliased input)
     # outputs
     pool_out_ref,  # (1, kvs, 2, P, dh) the same page (aliased)
-    o_ref,         # (1, Sp, Hq, dh)
+    o_ref,         # (1, kvs, M, dh)
     # scratch
     m_ref, l_ref, acc_ref,
     *, n_pages: int, n_chunk: int, window: int,
@@ -90,39 +92,34 @@ def _fused_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _attend(k, v, kv_pos, kv_valid):
-        # k, v: (kvs, P, dh) f32; kv_pos/kv_valid: (P,)
-        q = q_ref[0].astype(jnp.float32)              # (Sp, Hq, dh)
-        Sp, Hq, dh = q.shape
-        kvs = k.shape[0]
-        rep = Hq // kvs
-        scale = 1.0 / math.sqrt(dh)
-        qg = (q.reshape(Sp, kvs, rep, dh) * scale).transpose(1, 0, 2, 3)
-        s = jax.lax.dot_general(qg, k, (((3,), (2,)), ((0,), (0,))),
+    def _attend(k, v, kv_pos):
+        # k, v: (kvs, P, dh) f32; kv_pos: (1, P) int32 (-1 = empty)
+        q = q_ref[0].astype(jnp.float32)              # (kvs, M, dh)
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        s = jax.lax.dot_general(q * scale, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
-        # s: (kvs, Sp, rep, P)
-        qp = qpos_ref[0]                              # (Sp,)
-        ok = kv_valid[None, :] & (kv_pos[None, :] <= qp[:, None])
+        # s: (kvs, M, P)
+        qp = qpos_ref[0]                              # (M, 1)
+        ok = (kv_pos >= 0) & (kv_pos <= qp)           # (M, P)
         if window > 0:
-            ok = ok & (kv_pos[None, :] > qp[:, None] - window)
-        s = jnp.where(ok[None, :, None, :], s, NEG_INF)
-        m_prev = m_ref[...]                           # (kvs, Sp, rep)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
+            ok = ok & (kv_pos > qp - window)
+        s = jnp.where(ok[None], s, NEG_INF)
+        m_prev = m_ref[...]                           # (kvs, M, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        pv = jax.lax.dot_general(p, v, (((3,), (1,)), ((0,), (0,))),
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
                                  preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
+        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 
     if n_pages > 0:
         @pl.when(j < n_pages)
         def _prefix_page():
-            k = pool_ref[0, :, 0].astype(jnp.float32)     # (kvs, P, dh)
-            v = pool_ref[0, :, 1].astype(jnp.float32)
-            pj = kvpos_ref[0, 0]                          # (P,)
-            _attend(k, v, pj, pj >= 0)
+            _attend(pool_ref[0, :, 0].astype(jnp.float32),
+                    pool_ref[0, :, 1].astype(jnp.float32),
+                    kvpos_ref[0, 0])
             # visited blocks must be written back explicitly — the
             # output VMEM block is not seeded from the aliased input
             pool_out_ref[...] = pool_ref[...]
@@ -131,23 +128,42 @@ def _fused_kernel(
     def _chunk_page():
         kc = knew_ref[0, 0]                               # (kvs, P, dh)
         vc = vnew_ref[0, 0]
-        pj = cpos_ref[0, 0]                               # (P,)
         _attend(kc.astype(jnp.float32), vc.astype(jnp.float32),
-                pj, pj >= 0)
+                cpos_ref[0, 0])
         # in-pass scatter: chunk start is page-aligned, so sub-block
-        # token t has in-page offset t; padded tokens (pj < 0, the
+        # token t has in-page offset t; padded tokens (position < 0, the
         # trailing partial page) keep the old pool bytes
-        new = jnp.stack([kc, vc], axis=1).astype(pool_out_ref.dtype)
-        keep = (pj >= 0)[None, None, :, None]
-        pool_out_ref[0] = jnp.where(keep, new, pool_ref[0])
+        keep = ckeep_ref[0, 0] >= 0                       # (P, 1)
+        dt = pool_out_ref.dtype
+        pool_out_ref[0, :, 0] = jnp.where(keep, kc.astype(dt),
+                                          pool_ref[0, :, 0])
+        pool_out_ref[0, :, 1] = jnp.where(keep, vc.astype(dt),
+                                          pool_ref[0, :, 1])
 
     @pl.when(j == n_pages + n_chunk - 1)
     def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-20)[..., None]
-        out = acc_ref[...] / denom                    # (kvs, Sp, rep, dh)
-        kvs, Sp, rep, dh = out.shape
-        out = out.transpose(1, 0, 2, 3).reshape(Sp, kvs * rep, dh)
-        o_ref[0] = out.astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-20)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _vmem_limit(rows: int, dh: int, P: int, q_dtype, pool_dtype) -> int:
+    """Scoped-VMEM request for one grid step: the resident query and
+    output blocks and the f32 accumulator (``rows`` = kv heads x padded
+    chunk tokens x heads per group), the lane-padded (rows, 1) softmax
+    state and query positions, the (rows, P) score temporaries, and the
+    double-buffered page blocks; doubled for the compiler's own
+    temporaries.  A 512-token chunk of an 8-head, dh-256 model asks for
+    about 57 MiB of the 128 MiB a v5e core holds."""
+    lane = 128
+    qb = jnp.dtype(q_dtype).itemsize
+    pb = jnp.dtype(pool_dtype).itemsize
+    f32 = 4
+    blocks = 2 * 2 * rows * dh * qb            # q in, attention out
+    blocks += 2 * rows * lane * 4              # query positions
+    blocks += 2 * 4 * 2 * P * dh * pb          # pool page in/out, k/v new
+    scratch = rows * dh * f32 + 2 * rows * lane * f32
+    temps = 2 * rows * max(P, lane) * f32 + rows * dh * f32
+    return min(2 * (blocks + scratch + temps), 100 * 2 ** 20)
 
 
 def _pad_chunk(q, k_new, v_new, q_positions, P):
@@ -203,8 +219,18 @@ def chunk_prefill_attention(q, k_new, v_new, pool, page_table,
     scatter_pages = jnp.take_along_axis(
         page_table, slot0 // P, axis=1).astype(jnp.int32)
 
-    kvpos_pg = kv_positions.reshape(B, mps, P)
-    cpos_pg = qpos.reshape(B, NC, P)
+    M = Sp * rep
+    # queries grouped per kv head, one row per (token, head-in-group):
+    # each page step is then two batched 2-D matmuls over the kv heads
+    q_g = q.reshape(B, Sp, kvs, rep, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, kvs, M, dh)
+    qpos_col = jnp.repeat(qpos, rep, axis=1).reshape(B, M, 1)
+    # positions as (1, P) rows (the mask's key axis) and, for the
+    # scatter, as (P, 1) columns: every position block then spans its
+    # array's last two dimensions whole, as the TPU tiling requires
+    kvpos_pg = kv_positions.reshape(B, mps, 1, P)
+    cpos_pg = qpos.reshape(B, NC, 1, P)
+    ckeep_pg = qpos.reshape(B, NC, P, 1)
     knew_pg = k_new.reshape(B, NC, P, kvs, dh).transpose(0, 1, 3, 2, 4)
     vnew_pg = v_new.reshape(B, NC, P, kvs, dh).transpose(0, 1, 3, 2, 4)
 
@@ -214,13 +240,13 @@ def chunk_prefill_attention(q, k_new, v_new, pool, page_table,
         return (b, 0, 0, 0)
 
     def qpos_index(b, j, pt, sp):
-        return (b, 0)
+        return (b, 0, 0)
 
     def kvpos_index(b, j, pt, sp):
-        return (b, jnp.minimum(j, mps - 1), 0)
+        return (b, jnp.minimum(j, mps - 1), 0, 0)
 
     def chunk_index(b, j, pt, sp):
-        return (b, jnp.clip(j - n_pages, 0, NC - 1), 0)
+        return (b, jnp.clip(j - n_pages, 0, NC - 1), 0, 0)
 
     def chunk_kv_index(b, j, pt, sp):
         return (b, jnp.clip(j - n_pages, 0, NC - 1), 0, 0, 0)
@@ -235,47 +261,74 @@ def chunk_prefill_attention(q, k_new, v_new, pool, page_table,
         def pool_index(b, j, pt, sp):
             return (sp[b, j], 0, 0, 0, 0)
 
-    def o_index(b, j, pt, sp):
-        return (b, 0, 0, 0)
-
     kernel = functools.partial(_fused_kernel, n_pages=n_pages,
                                n_chunk=NC, window=window)
     # inputs after the 2 prefetch args: q=0 qpos=1 kvpos=2 cpos=3
-    # knew=4 vnew=5 pool=6 → global index 8 aliases output 0 (the pool)
+    # ckeep=4 knew=5 vnew=6 pool=7 → global index 9 aliases output 0
     new_pool, out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, Sp, Hq, dh), q_index),
-                pl.BlockSpec((1, Sp), qpos_index),
-                pl.BlockSpec((1, 1, P), kvpos_index),
-                pl.BlockSpec((1, 1, P), chunk_index),
+                pl.BlockSpec((1, kvs, M, dh), q_index),
+                pl.BlockSpec((1, M, 1), qpos_index),
+                pl.BlockSpec((1, 1, 1, P), kvpos_index),
+                pl.BlockSpec((1, 1, 1, P), chunk_index),
+                pl.BlockSpec((1, 1, P, 1), chunk_index),
                 pl.BlockSpec((1, 1, kvs, P, dh), chunk_kv_index),
                 pl.BlockSpec((1, 1, kvs, P, dh), chunk_kv_index),
                 pl.BlockSpec((1, kvs, 2, P, dh), pool_index),
             ],
             out_specs=[
                 pl.BlockSpec((1, kvs, 2, P, dh), pool_index),
-                pl.BlockSpec((1, Sp, Hq, dh), o_index),
+                pl.BlockSpec((1, kvs, M, dh), q_index),
             ],
             scratch_shapes=[
-                pltpu.VMEM((kvs, Sp, rep), jnp.float32),
-                pltpu.VMEM((kvs, Sp, rep), jnp.float32),
-                pltpu.VMEM((kvs, Sp, rep, dh), jnp.float32),
+                pltpu.VMEM((kvs, M, 1), jnp.float32),
+                pltpu.VMEM((kvs, M, 1), jnp.float32),
+                pltpu.VMEM((kvs, M, dh), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-            jax.ShapeDtypeStruct((B, Sp, Hq, dh), q.dtype),
+            jax.ShapeDtypeStruct((B, kvs, M, dh), q.dtype),
         ],
-        input_output_aliases={8: 0},
+        input_output_aliases={9: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(kvs * M, dh, P, q.dtype,
+                                         pool.dtype)),
         interpret=_auto_interpret(interpret),
     )(page_table.astype(jnp.int32), scatter_pages,
-      q, qpos.astype(jnp.int32), kvpos_pg.astype(jnp.int32),
-      cpos_pg.astype(jnp.int32), knew_pg, vnew_pg, pool)
+      q_g, qpos_col.astype(jnp.int32), kvpos_pg.astype(jnp.int32),
+      cpos_pg.astype(jnp.int32), ckeep_pg.astype(jnp.int32),
+      knew_pg, vnew_pg, pool)
+    out = out.reshape(B, kvs, Sp, rep, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, Sp, Hq, dh)
     return out[:, :S], new_pool
+
+
+def chunk_prefill_sharded(mesh, q, k_new, v_new, pool, page_table,
+                          kv_positions, q_positions, *, window: int = 0,
+                          attend_prefix: bool = True, interpret=None):
+    """``chunk_prefill_attention`` on an instance mesh (``(rep, sp,
+    tp)`` axes, or ``None`` for a single device).  GSPMD cannot
+    partition a Mosaic kernel, so over several devices the call runs
+    under ``shard_map``: kv heads and their query groups split over
+    ``tp`` (the padding plan makes both divisible), everything else is
+    replicated, and each device walks its own heads' pages."""
+    call = functools.partial(chunk_prefill_attention, window=window,
+                             attend_prefix=attend_prefix,
+                             interpret=interpret)
+    args = (q, k_new, v_new, pool, page_table, kv_positions, q_positions)
+    if mesh is None or mesh.size == 1:
+        return call(*args)
+    from jax.sharding import PartitionSpec as P
+    heads = P(None, None, "tp", None)
+    return jax.shard_map(
+        call, mesh=mesh,
+        in_specs=(heads, heads, heads, P(None, "tp"), P(), P(), P()),
+        out_specs=(heads, P(None, "tp")), check_vma=False)(*args)
 
 
 def chunk_prefill_jnp(q, k_new, v_new, pool, page_table, kv_positions,

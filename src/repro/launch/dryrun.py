@@ -30,7 +30,7 @@ from repro.core.padding import make_plan
 from repro.launch import sharding as SH
 from repro.launch import specs as SP
 from repro.launch.hlo_analysis import collective_bytes
-from repro.launch.mesh import (batch_axes, make_production_mesh,
+from repro.launch.mesh import (batch_axes, make_mesh, make_production_mesh,
                                model_axis_size)
 from repro.models import model as M
 from repro.training.optimizer import adamw
@@ -146,7 +146,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     if mesh_shape is not None:
         # §Perf: alternative (data, model) factorization of the same 256
         # chips — the Gyges thesis (lower TP when possible) at pod scale.
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()

@@ -46,17 +46,26 @@ class Layout:
                 else f"TP{self.tp}")
 
 
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes: the sharding rules here are
+    ``with_sharding_constraint`` hints that GSPMD propagates, which
+    Explicit axes (``jax.make_mesh``'s default) refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (data=16, model=16) = 256 chips.
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n: int = 8):
     """A single host's instance-group mesh (Gyges transformation scope)."""
-    return jax.make_mesh((n,), ("worker",))
+    return make_mesh((n,), ("worker",))
 
 
 def make_instance_mesh(devices, layout):

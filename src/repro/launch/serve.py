@@ -4,32 +4,30 @@ The §5 scheduler (``GygesScheduler`` by default) routes every request and
 decides every transformation; this module only parses arguments, builds
 the trace, and prints what the control plane did.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3-8b \
-        [--instances 2] [--requests 16] [--long-every 5] [--scheduler gyges]
+    PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b \
+        [--smoke] [--instances N] [--requests 16] [--long-every 5]
 
-With one CPU device this degenerates to a single TP1 instance; under 8
-fake host devices (set below by default) it demonstrates the full
-dynamic: short requests spread over TP1 instances, a long request
-triggers a scheduler-issued live scale-up (``Engine.transform``, one
-§4.3 schedule step per decode iteration), and the Alg-2 scan decomposes
-the instance once the long request drains.
+It serves on whatever devices JAX finds.  One device gives one TP1
+instance; with several (a TPU host, or fake CPU devices from
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``) it demonstrates
+the full dynamic: short requests spread over TP1 instances, a long
+request triggers a scheduler-issued live scale-up (``Engine.transform``,
+one §4.3 schedule step per decode iteration), and the Alg-2 scan
+decomposes the instance once the long request drains.  ``--smoke`` swaps
+in the reduced test-size config.
 """
 from __future__ import annotations
 
 import argparse
-import os
-
-# must precede the jax import so the fake-device flag takes effect
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-
 import dataclasses
+from typing import Optional
 
 import jax
 import numpy as np
 
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.core.scheduler import SCHEDULERS, PrefillPolicy, ScaleUp
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.cluster import ClusterEngine
 from repro.serving.request import ServeRequest
 
@@ -54,10 +52,34 @@ def build_trace(n: int, long_every: int, cluster: ClusterEngine,
     return reqs
 
 
+def build_cluster(cfg, devices, *, instances: int, max_seq: int,
+                  max_batch: int = 0, page_tokens: int = 16,
+                  prefill_budget: int = 0, prefill_mode: str = "mixed",
+                  scheduler: str = "gyges",
+                  rng: Optional[jax.Array] = None) -> ClusterEngine:
+    """The serving control plane over ``devices``: ``instances`` TP1
+    engines of ``len(devices) // instances`` devices each, ``max_batch``
+    slots apiece (0 = one per device), and a chunked-prefill policy when
+    ``prefill_budget`` is set (0 = whole-prompt prefill)."""
+    w = len(devices) // instances
+    policy = (PrefillPolicy(token_budget=prefill_budget, mode=prefill_mode,
+                            long_threshold=max_seq // w or 1, order="sjf")
+              if prefill_budget else None)
+    return ClusterEngine(
+        cfg, devices, n_instances=instances,
+        max_batch=max_batch or w, max_seq=max_seq,
+        page_tokens=page_tokens,
+        scheduler=None if scheduler == "gyges"
+        else SCHEDULERS[scheduler](),
+        prefill_policy=policy, rng=rng)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b", choices=ASSIGNED_ARCHS)
-    ap.add_argument("--instances", type=int, default=2)
+    ap.add_argument("--arch", default="gemma-2b", choices=ASSIGNED_ARCHS)
+    ap.add_argument("--instances", type=int, default=0,
+                    help="TP1 instances (0 = two when the devices allow, "
+                         "else one)")
     ap.add_argument("--scheduler", default="gyges",
                     choices=sorted(SCHEDULERS))
     ap.add_argument("--requests", type=int, default=16)
@@ -73,27 +95,27 @@ def main() -> None:
     ap.add_argument("--prefill-mode", default="mixed",
                     choices=("prefill", "decode", "mixed"),
                     help="prefill/decode priority when budgeted")
-    ap.add_argument("--smoke", action="store_true", default=True,
-                    help="reduced model config (default)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced test-size model config")
+    ap.add_argument("--dtype", default=None,
+                    help="override the config's dtype (e.g. float32)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced() if args.smoke \
-        else get_config(args.arch)
-    cfg = dataclasses.replace(cfg, dtype="float32")
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = cfg.reduced()
+    if args.dtype:
+        cfg = dataclasses.replace(cfg, dtype=args.dtype)
     devs = jax.devices()
-    w = len(devs) // args.instances
-    policy = (PrefillPolicy(token_budget=args.prefill_budget,
-                            mode=args.prefill_mode,
-                            long_threshold=args.max_seq // w or 1,
-                            order="sjf")
-              if args.prefill_budget else None)
-    cluster = ClusterEngine(
-        cfg, devs, n_instances=args.instances,
-        max_batch=args.max_batch or w, max_seq=args.max_seq,
-        scheduler=None if args.scheduler == "gyges"
-        else SCHEDULERS[args.scheduler](),
-        prefill_policy=policy)
-    print(f"[serve] {cfg.name}: {args.instances} instances x {w} devices, "
+    instances = args.instances or min(2, len(devs))
+    w = len(devs) // instances
+    cluster = build_cluster(
+        cfg, devs, instances=instances, max_seq=args.max_seq,
+        max_batch=args.max_batch,
+        prefill_budget=args.prefill_budget, prefill_mode=args.prefill_mode,
+        scheduler=args.scheduler)
+    print(f"[serve] {cfg.name}: {instances} instances x {w} devices, "
           f"scheduler={cluster.scheduler.name}, "
           f"TP1 ceiling {cluster.engines[0].max_seq_at(1)} tok, "
           f"TP{w} ceiling {cluster.engines[0].max_seq_at(w)} tok")

@@ -22,6 +22,7 @@ import numpy as np
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.core.padding import make_plan
 from repro.launch import sharding as SH
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.training import (DataConfig, SyntheticStream, adamw,
                             make_train_step, wsd)
@@ -51,7 +52,7 @@ def main() -> None:
     mesh = None
     if args.mesh:
         shape = tuple(int(x) for x in args.mesh.split(","))
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         plan = make_plan(cfg, shape[1], mode="lane")
     else:
         plan = make_plan(cfg, 1)

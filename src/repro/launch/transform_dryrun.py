@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ASSIGNED_ARCHS, get_config
 from repro.core.padding import make_plan
 from repro.launch.hlo_analysis import collective_bytes
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import param_specs
 from repro.core.instance import param_pspecs as inst_pspecs
 from repro.models.model import PAGE_TOKENS
@@ -38,8 +39,8 @@ def run(arch: str, tokens_per_seq: int, batch_per_rep: int = 4):
     plan = make_plan(cfg, 4, mode="page")
     # 256 chips = 64 hosts x 4 workers; host axis shards independent
     # instance groups, (rep, tp) is the transformable factorization.
-    mesh_tp1 = jax.make_mesh((64, 4, 1), ("host", "rep", "tp"))
-    mesh_tp4 = jax.make_mesh((64, 1, 4), ("host", "rep", "tp"))
+    mesh_tp1 = make_mesh((64, 4, 1), ("host", "rep", "tp"))
+    mesh_tp4 = make_mesh((64, 1, 4), ("host", "rep", "tp"))
 
     # ---- weights: replicated per host at TP1 -> column/row sharded ------
     p_sds = param_specs(cfg, plan)

@@ -110,7 +110,7 @@ def attention_chunk(p: Params, x: jax.Array, cfg: ModelConfig,
                     first_chunk: bool = False,
                     identity_pages: bool = False,
                     use_kernel: bool = False,
-                    sp: int = 1
+                    sp: int = 1, mesh=None
                     ) -> Tuple[jax.Array, pp.PagedState]:
     """Chunk-continuation prefill: queries are the chunk's tokens
     (x: (B,S,d), positions: (B,S) global), keys are the CACHED prefix
@@ -130,16 +130,22 @@ def attention_chunk(p: Params, x: jax.Array, cfg: ModelConfig,
     + concat of an all-invalid prefix is skipped in both paths.
     use_kernel=True: the fused Pallas kernel walks the paged pool page
     by page (no dense prefix materialization) and scatters the chunk's
-    K/V in the same pass; shapes the kernel doesn't cover fall back to
-    the jnp path automatically."""
+    K/V in the same pass; a shape the kernel doesn't cover is an error
+    (sequence-parallel layouts, ``sp > 1``, take the jnp path).  ``mesh``
+    is the instance mesh the cache lives on: over several devices the
+    kernel runs per kv-head shard (``chunk_prefill_sharded``)."""
     B, S, d = x.shape
     q, k, v = _project_qkv(p, x, cfg, plan, positions)
-    if use_kernel and sp == 1 and CP.chunk_prefill_eligible(
-            cache.pool, S, cache.capacity):
+    if use_kernel and sp == 1:
+        if not CP.chunk_prefill_eligible(cache.pool, S, cache.capacity):
+            raise ValueError(
+                f"fused chunk-prefill kernel cannot take this chunk: pool "
+                f"rank {cache.pool.ndim}, {S} tokens, capacity "
+                f"{cache.capacity}")
         pool_c = pp.canonical(cache.pool, layout)
-        attn, pool_c = CP.chunk_prefill_attention(
-            q, k, v, pool_c, cache.page_table, cache.positions, positions,
-            window=window, attend_prefix=not first_chunk)
+        attn, pool_c = CP.chunk_prefill_sharded(
+            mesh, q, k, v, pool_c, cache.page_table, cache.positions,
+            positions, window=window, attend_prefix=not first_chunk)
         cache = pp.adopt_chunk_pool(cache, pool_c, positions, layout)
     else:
         if first_chunk:
@@ -496,7 +502,7 @@ def apply_block_chunk(kind: str, p: Params, cfg: ModelConfig,
                       first_chunk: bool = False,
                       identity_pages: bool = False,
                       use_kernel: bool = False,
-                      sp: int = 1):
+                      sp: int = 1, mesh=None):
     """Prefill-chunk forward for one block: like ``apply_block_seq``
     but continuing from per-slot cache state.  x: (B,S,d), positions:
     (B,S) global.  Attention kinds attend over cached prefix + chunk
@@ -511,7 +517,7 @@ def apply_block_chunk(kind: str, p: Params, cfg: ModelConfig,
             p["attn"], h, cfg, plan, positions, cache,
             window=_window_of(kind, cfg), layout=layout,
             first_chunk=first_chunk, identity_pages=identity_pages,
-            use_kernel=use_kernel, sp=sp)
+            use_kernel=use_kernel, sp=sp, mesh=mesh)
         x = x + attn_out
         h = Lyr.rmsnorm(x, p["ln2"], cfg.norm_eps)
         if kind == MOE:

@@ -75,7 +75,11 @@ def _run_groups(body, carry, xs, unroll: bool):
 # Init
 # ---------------------------------------------------------------------------
 
+@partial(jax.jit, static_argnames=("cfg", "plan"))
 def init_params(rng, cfg: ModelConfig, plan: PaddingPlan) -> Dict[str, Any]:
+    """Seeded random weights, as one compiled program, so XLA can fuse
+    each float32 draw with its cast to ``cfg.dtype`` instead of running
+    (and compiling) every op eagerly."""
     unit = pattern_unit(cfg)
     G, R = group_counts(cfg)
     dt = jnp.dtype(cfg.dtype)
@@ -383,7 +387,7 @@ def prefill_chunk(params, cfg: ModelConfig, plan: PaddingPlan,
                   first_chunk: bool = False,
                   identity_pages: bool = False,
                   use_kernel: bool = False,
-                  sp: int = 1
+                  sp: int = 1, mesh=None
                   ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Run ONE prefill chunk and fold it into the caches.
 
@@ -402,7 +406,9 @@ def prefill_chunk(params, cfg: ModelConfig, plan: PaddingPlan,
     token dropping the dropped set can differ from whole-prompt
     evaluation, exactly as it differs across batch shapes.  Encoder /
     vision frontends are not chunkable (their memory is not causal);
-    the engine keeps those prompts whole."""
+    the engine keeps those prompts whole.  ``mesh`` is the instance mesh
+    the caches live on (the fused kernel runs per kv-head shard there).
+    """
     if cfg.encoder is not None or cfg.vision is not None:
         raise NotImplementedError(
             "chunked prefill covers causal decoder-only models")
@@ -422,7 +428,8 @@ def prefill_chunk(params, cfg: ModelConfig, plan: PaddingPlan,
                                      positions, gcaches[i], layout,
                                      first_chunk=first_chunk,
                                      identity_pages=identity_pages,
-                                     use_kernel=use_kernel, sp=sp)
+                                     use_kernel=use_kernel, sp=sp,
+                                     mesh=mesh)
         return xc, tuple(gcaches)
 
     xs: Tuple = tuple(params["blocks"]) + tuple(caches["groups"])
@@ -434,7 +441,7 @@ def prefill_chunk(params, cfg: ModelConfig, plan: PaddingPlan,
                        positions, caches["rem"][i], layout,
                        first_chunk=first_chunk,
                        identity_pages=identity_pages,
-                       use_kernel=use_kernel, sp=sp)
+                       use_kernel=use_kernel, sp=sp, mesh=mesh)
         new_rem.append(c)
 
     out = {"groups": list(new_group_caches), "rem": new_rem}
@@ -608,6 +615,28 @@ def _boundary_put(x: jax.Array, mesh, cur: Optional[frozenset]
     return x, devs
 
 
+# The per-layer paths run one compiled program per layer.  Dispatched op
+# by op instead, every Pallas call (and every shard_map around one)
+# would be traced, lowered and compiled anew on each call.
+@partial(jax.jit, static_argnames=("kind", "cfg", "plan", "layout",
+                                   "identity_pages"))
+def _block_decode(p, x, positions, cache, *, kind, cfg, plan, layout,
+                  identity_pages):
+    return B.apply_block_decode(kind, p, cfg, plan, x, positions, cache,
+                                layout, identity_pages=identity_pages)
+
+
+@partial(jax.jit, static_argnames=("kind", "cfg", "plan", "layout",
+                                   "first_chunk", "identity_pages",
+                                   "use_kernel", "mesh"))
+def _block_chunk(p, x, positions, cache, *, kind, cfg, plan, layout,
+                 first_chunk, identity_pages, use_kernel, mesh):
+    return B.apply_block_chunk(kind, p, cfg, plan, x, positions, cache,
+                               layout, first_chunk=first_chunk,
+                               identity_pages=identity_pages,
+                               use_kernel=use_kernel, mesh=mesh)
+
+
 def decode_step_layers(layers: List[Dict[str, Any]],
                        static: Dict[str, Any], cfg: ModelConfig,
                        plan: PaddingPlan, tokens: jax.Array,
@@ -634,9 +663,9 @@ def decode_step_layers(layers: List[Dict[str, Any]],
     new_layers = []
     for i, layer in enumerate(layers):
         x, cur = _boundary_put(x, layer.get("mesh"), cur)
-        x, c = B.apply_block_decode(layer["kind"], layer["params"], cfg,
-                                    plan, x, pos2, layer["cache"], layout,
-                                    identity_pages=identity_pages)
+        x, c = _block_decode(layer["params"], x, pos2, layer["cache"],
+                             kind=layer["kind"], cfg=cfg, plan=plan,
+                             layout=layout, identity_pages=identity_pages)
         new_layers.append({**layer, "cache": c})
         if on_layer is not None:
             on_layer(i)
@@ -675,11 +704,12 @@ def prefill_chunk_layers(layers: List[Dict[str, Any]],
     new_caches = []
     for layer, c in zip(layers, slot_caches):
         x, cur = _boundary_put(x, layer.get("mesh"), cur)
-        x, c = B.apply_block_chunk(layer["kind"], layer["params"], cfg,
-                                   plan, x, positions, c, layout,
-                                   first_chunk=first_chunk,
-                                   identity_pages=identity_pages,
-                                   use_kernel=use_kernel)
+        x, c = _block_chunk(layer["params"], x, positions, c,
+                            kind=layer["kind"], cfg=cfg, plan=plan,
+                            layout=layout, first_chunk=first_chunk,
+                            identity_pages=identity_pages,
+                            use_kernel=use_kernel,
+                            mesh=layer.get("mesh") if use_kernel else None)
         new_caches.append(c)
     x, cur = _boundary_put(x, static_mesh, cur)
     logits = lm_logits(static, cfg, plan, x[:, -1:, :])

@@ -84,7 +84,7 @@ class ClusterEngine:
                  dwell_steps: int = 8, layout: str = "header_centric",
                  transform_attn: bool = True,
                  prefill_policy: Optional[PrefillPolicy] = None,
-                 clock=None):
+                 clock=None, fused_chunk_kernel: Optional[bool] = None):
         if n_instances < 1 or len(devices) < n_instances:
             raise ValueError(f"{n_instances} instances need at least "
                              f"{n_instances} of {len(devices)} devices")
@@ -105,14 +105,17 @@ class ClusterEngine:
             from repro.models import model as M
             params = M.init_params(jax.random.fold_in(rng, 1), cfg,
                                    self.plan)
-        self._params_src = params               # revive() re-shards these
+        # revive() re-shards these; host memory keeps that spare copy off
+        # every device once the engines' own weights transform
+        self._params_src = jax.device_get(params)
         self.prefill_policy = prefill_policy or PrefillPolicy()
         self.engines: List[Engine] = [
             Engine(cfg, params=params, max_batch=max_batch,
                    max_seq=max_seq, page_tokens=page_tokens, rng=rng,
                    layout=layout, devices=list(devices[k * W:(k + 1) * W]),
                    transform_attn=transform_attn, iid=k, plan=self.plan,
-                   prefill_policy=self.prefill_policy, clock=self._clock)
+                   prefill_policy=self.prefill_policy, clock=self._clock,
+                   fused_chunk_kernel=fused_chunk_kernel)
             for k in range(n_instances)]
         if scheduler is None:
             base = self.engines[0].max_seq_at(1)

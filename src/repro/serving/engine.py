@@ -234,14 +234,15 @@ class Engine:
         # GSPMD-local identity gather/scatter path is always valid here.
         use_kernel_c = self.fused_chunk_kernel
 
-        @partial(jax.jit, static_argnames=("first_chunk", "sp"))
+        @partial(jax.jit, static_argnames=("first_chunk", "sp", "mesh"))
         def _chunk(params, tokens, start_pos, sub, first_chunk=False,
-                   sp=1):
+                   sp=1, mesh=None):
             return M.prefill_chunk(params, cfgc, planc, tokens,
                                    start_pos, sub, layoutc,
                                    first_chunk=first_chunk,
                                    identity_pages=True,
-                                   use_kernel=use_kernel_c, sp=sp)
+                                   use_kernel=use_kernel_c, sp=sp,
+                                   mesh=mesh)
 
         self._prefill_chunk_jit = _chunk
 
@@ -963,7 +964,8 @@ class Engine:
             logits, sub = self._prefill_chunk_jit(self.params, tokens,
                                                   start_a, sub,
                                                   first_chunk=start == 0,
-                                                  sp=self.par_layout.sp)
+                                                  sp=self.par_layout.sp,
+                                                  mesh=self.mesh)
             if ext:
                 self.spill_slot(slot, sub)
             else:

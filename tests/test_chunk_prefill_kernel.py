@@ -286,6 +286,69 @@ def test_fused_path_hlo_has_no_pool_all_gather():
     assert "local_bytes 0" in out
 
 
+def test_sharded_kernel_matches_single_device():
+    """Over several devices the kernel runs under shard_map (kv heads
+    over ``tp``, replicated over ``rep``): same attention and the same
+    pool bytes as one un-sharded call, on 4 fake devices as rep2 x tp2."""
+    out = run_py("""
+        import jax, jax.numpy as jnp
+        import numpy as np
+        from jax.sharding import Mesh
+        from repro.kernels import chunk_prefill as CP
+
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 1, 2),
+                    ("rep", "sp", "tp"))
+        rng = np.random.default_rng(0)
+        B, Hq, kvs, P, mps, dh, S, done = 1, 8, 4, 8, 4, 16, 12, 16
+        cap = mps * P
+        pool = jnp.asarray(rng.normal(size=(B * mps, kvs, 2, P, dh)),
+                           jnp.float32)
+        pt = jnp.arange(B * mps, dtype=jnp.int32).reshape(B, mps)
+        kvpos = np.full((B, cap), -1, np.int32)
+        kvpos[:, :done] = np.arange(done)
+        qpos = jnp.asarray(done + np.arange(S)[None], jnp.int32)
+        q = jnp.asarray(rng.normal(size=(B, S, Hq, dh)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(B, S, kvs, dh)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(B, S, kvs, dh)), jnp.float32)
+        args = (q, k, v, pool, pt, jnp.asarray(kvpos), qpos)
+        want_o, want_p = CP.chunk_prefill_attention(*args, interpret=True)
+        got_o, got_p = jax.jit(lambda *a: CP.chunk_prefill_sharded(
+            mesh, *a, interpret=True))(*args)
+        np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                                   atol=1e-6, rtol=1e-6)
+        np.testing.assert_array_equal(np.asarray(got_p),
+                                      np.asarray(want_p))
+        print("SHARDED_KERNEL_OK")
+    """)
+    assert "SHARDED_KERNEL_OK" in out
+
+
+def test_kernel_refusal_is_an_error():
+    """With the kernel on, a chunk it cannot take (longer than the slot
+    capacity) raises instead of silently switching to the jnp path."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_config
+    from repro.core.padding import make_plan
+    from repro.models import blocks as B_
+    from repro.paged import pool as pp
+
+    cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
+                              dtype="float32")
+    plan = make_plan(cfg, 1)
+    B, S, P, mps = 1, 40, 8, 4                # capacity 32 < 40
+    p = B_.init_attention(jax.random.PRNGKey(0), cfg, plan)
+    x = jnp.zeros((B, S, cfg.d_model), jnp.float32)
+    pos = jnp.arange(S, dtype=jnp.int32)[None]
+    cache = pp.make_state(B * mps, plan.kv_slots, P, cfg.resolved_head_dim,
+                          B, mps, dtype=jnp.float32)
+    with pytest.raises(ValueError, match="cannot take this chunk"):
+        B_.attention_chunk(p, x, cfg, plan, pos, cache, first_chunk=True,
+                           use_kernel=True)
+
+
 def test_kernel_eligibility_gate():
     from repro.kernels.chunk_prefill import chunk_prefill_eligible
 

@@ -206,6 +206,86 @@ def test_merge_from_router_retry_keeps_every_request():
     assert "RETRY_MERGE_OK" in out
 
 
+def test_merge_with_fused_chunk_kernel_mid_session():
+    """TP1x4 -> TP4 merge with the fused chunk-prefill kernel on (the
+    TPU configuration; interpret mode here): a short request finishes
+    mid-session, so the long prompt's chunks run the per-layer session
+    path, the kernel under ``shard_map`` on migrated layers and alone on
+    the rest.  Streams equal an engine started at TP4, and the per-layer
+    path compiles each layer program once, not once per call."""
+    out = run_py("""
+        import dataclasses
+        import jax, numpy as np
+        from repro.configs import get_config
+        from repro.core.scheduler import PrefillPolicy, ScaleDown, ScaleUp
+        from repro.models import model as M
+        from repro.serving.cluster import ClusterEngine
+        from repro.serving.engine import Engine
+        from repro.serving.request import ServeRequest
+
+        cfg = dataclasses.replace(get_config("gemma-2b").reduced(),
+                                  dtype="float32", num_layers=6)
+        devs = jax.devices()[:4]
+        Q, PAGE, CHUNK = 64, 8, 16
+        policy = PrefillPolicy(token_budget=CHUNK, mode="mixed",
+                               long_threshold=Q, order="sjf")
+        cluster = ClusterEngine(cfg, devs, n_instances=4, max_batch=4,
+                                max_seq=Q, page_tokens=PAGE,
+                                prefill_policy=policy,
+                                fused_chunk_kernel=True)
+        target_chunks = []
+        for e in cluster.engines:
+            run_layers = e._run_chunk_layers
+            def counted(*a, _run=run_layers, _e=e):
+                target_chunks.append(_e.iid)
+                return _run(*a)
+            e._run_chunk_layers = counted
+        rng = np.random.default_rng(0)
+        mk = lambda rid, n, new: ServeRequest(
+            rid=rid, prompt=rng.integers(0, cfg.vocab_size,
+                                         size=n).tolist(),
+            max_new_tokens=new)
+        # request 0 finishes inside the session and frees the slot the
+        # long prompt needs; the others decode across its end
+        shorts = [mk(0, 8, 4), mk(1, 8, 16), mk(2, 8, 16), mk(3, 8, 16)]
+        long_r = mk(9, 10 * CHUNK, 8)       # 160 > the TP1 ceiling (64)
+        for r in shorts:
+            cluster.submit(r)
+        for _ in range(2):
+            cluster.step()
+        cluster.submit(long_r)
+        assert [a for a in cluster.actions
+                if isinstance(a, ScaleUp) and a.donor_iids], cluster.actions
+        n_compiles = M._block_chunk._cache_size()
+        cluster.run(max_steps=2000)
+        assert all(r.finished for r in shorts + [long_r])
+        assert [a for a in cluster.actions if isinstance(a, ScaleDown)]
+        assert cluster.stall_steps == 0, cluster.stall_steps
+        assert len(target_chunks) >= 2, target_chunks
+        # a few programs per assembly, not one per layer call
+        compiled = M._block_chunk._cache_size() - n_compiles
+        assert compiled <= 8 < len(target_chunks) * cfg.num_layers, (
+            compiled, len(target_chunks))
+
+        ref = Engine(cfg, params=cluster._params_src, max_batch=4,
+                     max_seq=4 * Q, page_tokens=PAGE, devices=devs,
+                     plan=cluster.plan, prefill_policy=policy,
+                     fused_chunk_kernel=True)
+        ref.transform(4)
+        while ref.transforming:
+            ref.step()
+        for got in shorts + [long_r]:
+            want = ServeRequest(rid=got.rid, prompt=list(got.prompt),
+                                max_new_tokens=got.max_new_tokens)
+            ref.submit(want)
+            ref.run_until_done(2000)
+            assert want.generated == got.generated, (
+                got.rid, want.generated, got.generated)
+        print("KERNEL_MERGE_OK", len(target_chunks))
+    """)
+    assert "KERNEL_MERGE_OK" in out
+
+
 # ---------------------------------------------------------------------------
 # Fast (single-device) coverage: merge policy + cross-pool data plane
 # ---------------------------------------------------------------------------

@@ -24,8 +24,8 @@ BODY = """
     # shrink the production mesh for CI
     def small_mesh(*, multi_pod=False):
         if multi_pod:
-            return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-        return jax.make_mesh((2, 4), ("data", "model"))
+            return mesh_mod.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        return mesh_mod.make_mesh((2, 4), ("data", "model"))
     DR.make_production_mesh = small_mesh
 
     shape = dataclasses.replace(SHAPES["{shape}"],
@@ -77,8 +77,9 @@ def test_transform_dryrun_small_mesh():
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.hlo_analysis import collective_bytes
-    mesh1 = jax.make_mesh((2, 4, 1), ("host", "rep", "tp"))
-    mesh4 = jax.make_mesh((2, 1, 4), ("host", "rep", "tp"))
+    from repro.launch.mesh import make_mesh
+    mesh1 = make_mesh((2, 4, 1), ("host", "rep", "tp"))
+    mesh4 = make_mesh((2, 1, 4), ("host", "rep", "tp"))
     # weights: replicated -> col-sharded over tp: no comm (slice only)
     w = jax.ShapeDtypeStruct((256, 512), jnp.bfloat16)
     wi = NamedSharding(mesh1, P(None, "tp"))
