@@ -215,6 +215,7 @@ def serve_one_chip(cfg, devices, *, max_seq: int, max_batch: int,
     import numpy as np
 
     from repro.launch.serve import build_cluster
+    from repro.serving import tracing
 
     t0 = time.perf_counter()
     cluster = build_cluster(cfg, devices, instances=1, max_seq=max_seq,
@@ -239,8 +240,12 @@ def serve_one_chip(cfg, devices, *, max_seq: int, max_batch: int,
     served = record_prefill_logits(eng)
 
     c0, t0 = clock.seconds, time.perf_counter()
+    first_span = tracing.RECORDER.opened
     m = cluster.run(reqs, max_steps=2_000)
     wall = time.perf_counter() - t0
+    chunks = tracing.rolled_up(
+        [s for s in tracing.RECORDER.spans() if s.index >= first_span],
+        "engine.chunk", "compiles")
     compiled = clock.seconds - c0
     assert all(r.finished and len(r.generated) == GEN for r in reqs), (
         [(r.rid, r.state, len(r.generated)) for r in reqs])
@@ -252,8 +257,7 @@ def serve_one_chip(cfg, devices, *, max_seq: int, max_batch: int,
           f"{[len(r.prompt) for r in reqs]}")
     print(f"[one-chip] wall {wall:.2f} s = compile {compiled:.2f} s "
           f"+ serve {wall - compiled:.2f} s; chunk compiles "
-          f"{eng.chunk_cache_misses}, chunk calls "
-          f"{eng.chunk_cache_misses + eng.chunk_cache_hits}")
+          f"{sum(n for _, n in chunks)}, chunk calls {len(chunks)}")
     stats = devices[0].memory_stats() or {}
     print(f"[one-chip] peak_bytes_in_use {stats.get('peak_bytes_in_use')}")
 

@@ -53,6 +53,7 @@ from repro.core.partition import Loan, PoolPartitionManager
 from repro.core.scheduler import (Action, BaseScheduler, GygesScheduler,
                                   PrefillPolicy, ScaleDown, ScaleUp,
                                   SchedulerConfig, Spill)
+from repro.serving import tracing
 from repro.serving.engine import Engine
 from repro.serving.metrics import summarize
 from repro.serving.request import ServeRequest, State
@@ -248,8 +249,11 @@ class ClusterEngine:
         req.t_submit = self._clock()
         self.scheduler.observe_arrival(req.t_submit, total)
         self.requests.append(req)
-        if not self._place(req):
-            self.waiting.append(req)
+        with tracing.span("cluster.route") as sp:
+            placed = self._place(req)
+            if not placed:
+                self.waiting.append(req)
+            sp.set(placed=int(placed), waiting=len(self.waiting))
 
     def _place(self, req: ServeRequest) -> bool:
         total = req.total_tokens
@@ -515,40 +519,52 @@ class ClusterEngine:
         engine iteration each (a transforming engine executes one §4.3
         schedule step before its decode), then finalize any completed
         splits (return device loans, revive parked donors)."""
-        self.scheduler.observe_time(self._clock())
-        # FCFS retry of the router queue (stop at the first unplaceable).
-        # Pop BEFORE placing: a merge inside _place prepends the donor's
-        # queue to self.waiting, so popping afterwards would drop one of
-        # those and leave the placed request queued twice.
-        while self.waiting:
-            req = self.waiting.pop(0)
-            if not self._place(req):
-                self.waiting.insert(0, req)
-                break
-        # Alg 2 over dwell-gated, non-transforming instances (spill
-        # participants cannot transform while their regions are open)
-        eligible = [
-            e for e in self._active_engines()
-            if e.tp > 1 and not e.transforming
-            and not e._spills and not e._hosted
-            and not e.awaiting_devices
-            and self.steps - self._last_transform_step[e.iid]
-            >= self.dwell_steps]
-        for act in self.scheduler.schedule_parallelism(
-                eligible, self._any_long_waiting()):
-            self._execute(act)
-        # elastic-SP layout scan (opt-in via SchedulerConfig.layouts),
-        # decision-for-decision with cluster_sim.Cluster.advance: any
-        # wide instance outside a transform window may re-factorize its
-        # degree to the (sp, tp) layout that wins its current workload
-        # mix — a same-degree §4.3 session, serving throughout
-        lay_eligible = [
-            e for e in self._active_engines()
-            if e.tp > 1 and not e.transforming
-            and not e._spills and not e._hosted
-            and not e.awaiting_devices]
-        for act in self.scheduler.decide_layout(lay_eligible):
-            self._execute(act)
+        with tracing.span("cluster.route") as sp:
+            self.scheduler.observe_time(self._clock())
+            # FCFS retry of the router queue (stop at the first
+            # unplaceable).  Pop BEFORE placing: a merge inside _place
+            # prepends the donor's queue to self.waiting, so popping
+            # afterwards would drop one of those and leave the placed
+            # request queued twice.
+            placed = 0
+            while self.waiting:
+                req = self.waiting.pop(0)
+                if not self._place(req):
+                    self.waiting.insert(0, req)
+                    break
+                placed += 1
+            sp.set(placed=placed, waiting=len(self.waiting))
+        with tracing.span("cluster.plan") as sp:
+            # Alg 2 over dwell-gated, non-transforming instances (spill
+            # participants cannot transform while their regions are
+            # open)
+            eligible = [
+                e for e in self._active_engines()
+                if e.tp > 1 and not e.transforming
+                and not e._spills and not e._hosted
+                and not e.awaiting_devices
+                and self.steps - self._last_transform_step[e.iid]
+                >= self.dwell_steps]
+            acts = self.scheduler.schedule_parallelism(
+                eligible, self._any_long_waiting())
+            for act in acts:
+                self._execute(act)
+            # elastic-SP layout scan (opt-in via SchedulerConfig.layouts),
+            # decision-for-decision with cluster_sim.Cluster.advance: any
+            # wide instance outside a transform window may re-factorize
+            # its degree to the (sp, tp) layout that wins its current
+            # workload mix — a same-degree §4.3 session, serving
+            # throughout
+            lay_eligible = [
+                e for e in self._active_engines()
+                if e.tp > 1 and not e.transforming
+                and not e._spills and not e._hosted
+                and not e.awaiting_devices]
+            lay_acts = self.scheduler.decide_layout(lay_eligible)
+            for act in lay_acts:
+                self._execute(act)
+            sp.set(eligible=len(eligible),
+                   actions=len(acts) + len(lay_acts))
         emitted = active = queued = 0
         for e in self._active_engines():
             # stall detection is computed from CONTROL-PLANE-visible
@@ -574,10 +590,11 @@ class ClusterEngine:
                 # now > transform_until + dwell) — keep re-stamping
                 # until the schedule drains
                 self._last_transform_step[e.iid] = self.steps
-        self._advance_partials()
-        self._finalize_releases()
-        self._finalize_spills()
-        self._feed_measured_costs()
+        with tracing.span("cluster.finalize"):
+            self._advance_partials()
+            self._finalize_releases()
+            self._finalize_spills()
+            self._feed_measured_costs()
         self.total_tokens += emitted
         self.steps += 1
         return {"active": active, "emitted": emitted,

@@ -59,6 +59,7 @@ from repro.core.padding import PaddingPlan, make_plan
 from repro.core.scheduler import PrefillPolicy
 from repro.launch.mesh import Layout
 from repro.models import model as M
+from repro.serving import tracing
 from repro.serving.request import ServeRequest, State
 
 
@@ -228,8 +229,8 @@ class Engine:
         # by (batch, chunk_len) shape — start_pos is traced, so every
         # chunk of the same shape reuses the compile; ``first_chunk``
         # is STATIC (empty-prefix chunks skip the prefix walk/gather
-        # entirely).  The key set mirrors jit's cache for observability
-        # (hits asserted in tests/test_chunked_prefill.py).  The slot
+        # entirely).  A chunk that compiled says so on its
+        # ``engine.chunk`` span (``serving.tracing``).  The slot
         # views are extracted with fresh identity page tables, so the
         # GSPMD-local identity gather/scatter path is always valid here.
         use_kernel_c = self.fused_chunk_kernel
@@ -256,9 +257,6 @@ class Engine:
                              sub, layoutc)
 
         self._prefill_whole_jit = _whole
-        self._chunk_keys: set = set()
-        self.chunk_cache_hits = 0
-        self.chunk_cache_misses = 0
         self._b1_tmpls: Dict = {}     # (kind, alloc) -> batch-1 template
 
     def _block_window(self, kind: str) -> int:
@@ -883,7 +881,9 @@ class Engine:
             if slot is not None:
                 for i, req in enumerate(self.waiting):
                     if self._admittable_now(req):
-                        self._begin_prefill(self.waiting.pop(i), slot)
+                        with tracing.span("engine.admit", rid=req.rid,
+                                          slot=slot):
+                            self._begin_prefill(self.waiting.pop(i), slot)
                         break
         if not self._prefilling:
             self._prefill_deferred = 0
@@ -919,65 +919,59 @@ class Engine:
         prefill completed (first token emitted), else 0."""
         prog = self._prefilling[slot]
         req = prog["req"]
-        if req.t_prefill_start is None:
-            req.t_prefill_start = self._clock()
-        if len(prog["chunks"]) == 1 and self._session is None:
-            # whole-prompt fast path: one prefill call on a fresh
-            # batch-1 cache (byte-identical to the pre-chunking engine).
-            # Mid-session the same plan falls through to the generic
-            # path below and runs as ONE first-chunk call on the
-            # per-layer assemblies — whole prompts no longer wait out
-            # transform sessions.
-            self._prefill_whole(req, slot)
-            del self._prefilling[slot]
-            return 1
         start = prog["done"]
         size = prog["chunks"][prog["ci"]]
-        tokens = jnp.asarray(req.prompt[start:start + size],
-                             jnp.int32)[None, :]
-        start_a = jnp.full((1,), start, jnp.int32)
-        if self._session is not None:
-            # mid-session: the chunk runs the per-layer path across the
-            # session's mixed-but-coherent device assemblies
-            logits = self._run_chunk_layers(slot, prog, tokens, start_a)
-        else:
-            # spilled slot past the local ceiling: the chunk computes on
-            # the EXTENDED view (local + host pages) and scatters back
-            # through spill_slot; jit keys on shapes, so the extended
-            # call simply traces its own entry
-            ext = (slot in self._spills
-                   and start + size > self._local_page_cap())
-            view = (self._assemble_spilled(slot) if ext
-                    else self._extract_slot_cache(slot))
-            sub = self._sanitize_sub(view, prog["rec"], start)
-            # mirror of jit's trace-cache key: chunk shape, pool
-            # allocation, the static first-chunk flag, AND the mesh
-            # factorization — a transform re-commits params/caches to
-            # new shardings, which retraces
-            key = (tokens.shape[0], tokens.shape[1], self.max_seq_alloc,
-                   self.tp, self.par_layout.sp, self.W, start == 0, ext)
-            if key in self._chunk_keys:
-                self.chunk_cache_hits += 1
+        with tracing.span("engine.chunk", rid=req.rid, start=start,
+                          size=size):
+            if req.t_prefill_start is None:
+                req.t_prefill_start = self._clock()
+            if len(prog["chunks"]) == 1 and self._session is None:
+                # whole-prompt fast path: one prefill call on a fresh
+                # batch-1 cache (byte-identical to the pre-chunking
+                # engine).  Mid-session the same plan falls through to
+                # the generic path below and runs as ONE first-chunk
+                # call on the per-layer assemblies — whole prompts no
+                # longer wait out transform sessions.
+                self._prefill_whole(req, slot)
+                del self._prefilling[slot]
+                return 1
+            tokens = jnp.asarray(req.prompt[start:start + size],
+                                 jnp.int32)[None, :]
+            start_a = jnp.full((1,), start, jnp.int32)
+            if self._session is not None:
+                # mid-session: the chunk runs the per-layer path across
+                # the session's mixed-but-coherent device assemblies
+                logits = self._run_chunk_layers(slot, prog, tokens,
+                                                start_a)
             else:
-                self._chunk_keys.add(key)
-                self.chunk_cache_misses += 1
-            logits, sub = self._prefill_chunk_jit(self.params, tokens,
-                                                  start_a, sub,
-                                                  first_chunk=start == 0,
-                                                  sp=self.par_layout.sp,
-                                                  mesh=self.mesh)
-            if ext:
-                self.spill_slot(slot, sub)
-            else:
-                self._adopt_slot_cache(sub, slot, start + size)
-            prog["rec"] = self._strip_pools(sub)
-        prog["done"] += size
-        prog["ci"] += 1
-        if prog["done"] >= len(req.prompt):
-            del self._prefilling[slot]
-            self._finish_prefill(req, slot, logits)
-            return 1
-        return 0
+                # spilled slot past the local ceiling: the chunk computes
+                # on the EXTENDED view (local + host pages) and scatters
+                # back through spill_slot; jit keys on shapes, so the
+                # extended call simply traces its own entry
+                ext = (slot in self._spills
+                       and start + size > self._local_page_cap())
+                with tracing.span("engine.chunk.view"):
+                    view = (self._assemble_spilled(slot) if ext
+                            else self._extract_slot_cache(slot))
+                    sub = self._sanitize_sub(view, prog["rec"], start)
+                with tracing.span("engine.chunk.run"):
+                    logits, sub = self._prefill_chunk_jit(
+                        self.params, tokens, start_a, sub,
+                        first_chunk=start == 0, sp=self.par_layout.sp,
+                        mesh=self.mesh)
+                with tracing.span("engine.chunk.adopt"):
+                    if ext:
+                        self.spill_slot(slot, sub)
+                    else:
+                        self._adopt_slot_cache(sub, slot, start + size)
+                    prog["rec"] = self._strip_pools(sub)
+            prog["done"] += size
+            prog["ci"] += 1
+            if prog["done"] >= len(req.prompt):
+                del self._prefilling[slot]
+                self._finish_prefill(req, slot, logits)
+                return 1
+            return 0
 
     def _run_chunk_layers(self, slot: int, prog: Dict, tokens: jax.Array,
                           start_a: jax.Array) -> jax.Array:
@@ -998,34 +992,37 @@ class Engine:
             prog["rec"] = self._strip_pools(M.init_decode_caches(
                 self.cfg, self.plan, 1, self.max_seq_alloc,
                 self.page_tokens, self.layout))
-        rec_layers = M.unstack_cache_tree(prog["rec"], self.cfg)
-        subs = []
-        for layer, rec in zip(s.layers, rec_layers):
-            tmpl = self._batch1_layer_tmpl(layer["kind"])
-            sub = self._extract_slot_tree(layer["cache"], tmpl, slot)
-            subs.append(self._sanitize_tree(sub, rec, start,
-                                            layer.get("mesh")))
-        logits, new_subs = M.prefill_chunk_layers(
-            s.layers, s.static, self.cfg, self.plan, tokens, start_a,
-            subs, self.layout, static_mesh=s.static_mesh,
-            first_chunk=start == 0, identity_pages=True,
-            use_kernel=self.fused_chunk_kernel)
-        for layer, sub in zip(s.layers, new_subs):
-            layer["cache"] = self._adopt_slot_tree(layer["cache"], sub,
-                                                   slot)
-        # the carry stays in the stacked format between chunks (one
-        # format everywhere, and sessions may drain mid-prefill) — but
-        # mid-cross-session its recurrent leaves come back committed to
-        # whichever assembly their layer was on, and jnp.stack cannot
-        # stack across disjoint device sets: land every leaf on the
-        # TARGET assembly first (the next chunk's sanitize re-pins each
-        # leaf to its layer's then-current mesh anyway)
-        rec_new = []
-        for sub in new_subs:
-            t = self._strip_tree(sub)
-            rec_new.append(jax.device_put(t, jax.tree.map(
-                lambda _: NamedSharding(s.mesh_to, P()), t)))
-        prog["rec"] = M.restack_cache_tree(rec_new, self.cfg)
+        with tracing.span("engine.chunk.view"):
+            rec_layers = M.unstack_cache_tree(prog["rec"], self.cfg)
+            subs = []
+            for layer, rec in zip(s.layers, rec_layers):
+                tmpl = self._batch1_layer_tmpl(layer["kind"])
+                sub = self._extract_slot_tree(layer["cache"], tmpl, slot)
+                subs.append(self._sanitize_tree(sub, rec, start,
+                                                layer.get("mesh")))
+        with tracing.span("engine.chunk.run"):
+            logits, new_subs = M.prefill_chunk_layers(
+                s.layers, s.static, self.cfg, self.plan, tokens, start_a,
+                subs, self.layout, static_mesh=s.static_mesh,
+                first_chunk=start == 0, identity_pages=True,
+                use_kernel=self.fused_chunk_kernel)
+        with tracing.span("engine.chunk.adopt"):
+            for layer, sub in zip(s.layers, new_subs):
+                layer["cache"] = self._adopt_slot_tree(layer["cache"],
+                                                       sub, slot)
+            # the carry stays in the stacked format between chunks (one
+            # format everywhere, and sessions may drain mid-prefill) — but
+            # mid-cross-session its recurrent leaves come back committed to
+            # whichever assembly their layer was on, and jnp.stack cannot
+            # stack across disjoint device sets: land every leaf on the
+            # TARGET assembly first (the next chunk's sanitize re-pins each
+            # leaf to its layer's then-current mesh anyway)
+            rec_new = []
+            for sub in new_subs:
+                t = self._strip_tree(sub)
+                rec_new.append(jax.device_put(t, jax.tree.map(
+                    lambda _: NamedSharding(s.mesh_to, P()), t)))
+            prog["rec"] = M.restack_cache_tree(rec_new, self.cfg)
         return logits
 
     def _batch1_layer_tmpl(self, kind: str):
@@ -1117,8 +1114,10 @@ class Engine:
 
     def _finish_prefill(self, req: ServeRequest, slot: int,
                         logits: jax.Array) -> None:
-        tok = int(_sample(logits[:, -1], req.temperature,
-                          jax.random.fold_in(self.rng, req.rid))[0])
+        nxt = _sample(logits[:, -1], req.temperature,
+                      jax.random.fold_in(self.rng, req.rid))
+        with tracing.span("engine.sync", kind="prefill"):
+            tok = int(nxt[0])
         req.generated.append(tok)
         req.t_first_token = self._clock()
         req.state = State.DECODE
@@ -1139,11 +1138,13 @@ class Engine:
         the slot (slot-partitioned pools make this a pure page-range
         copy — the page-friendly layout at work, paper Table 2 row 2)."""
         prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        sub = M.init_decode_caches(self.cfg, self.plan, 1,
-                                   self.max_seq_alloc, self.page_tokens,
-                                   self.layout)
-        logits, sub = self._prefill_whole_jit(self.params, prompt, sub)
-        self._adopt_slot_cache(sub, slot, len(req.prompt))
+        with tracing.span("engine.chunk.run"):
+            sub = M.init_decode_caches(self.cfg, self.plan, 1,
+                                       self.max_seq_alloc, self.page_tokens,
+                                       self.layout)
+            logits, sub = self._prefill_whole_jit(self.params, prompt, sub)
+        with tracing.span("engine.chunk.adopt"):
+            self._adopt_slot_cache(sub, slot, len(req.prompt))
         self._finish_prefill(req, slot, logits)
 
     def _adopt_slot_tree(self, dst, src, slot: int):
@@ -1492,17 +1493,23 @@ class Engine:
         assert self._session is None, (
             "spilled slots decode outside transform sessions")
         slot = r.slot
-        ext = self._assemble_spilled(slot)
-        tok = jnp.asarray([r.generated[-1]], jnp.int32)
-        pos = jnp.asarray([r.context_len - 1], jnp.int32)
-        logits, ext = self._decode(self.params, ext, tok, pos,
-                                   sp=self.par_layout.sp)
-        t = int(_sample(logits, 0.0, self.rng)[0])
-        if r.temperature > 0:
-            sub_rng = jax.random.fold_in(
-                jax.random.fold_in(self.rng, r.rid), r.context_len)
-            t = int(_sample(logits[0][None], r.temperature, sub_rng)[0])
-        self.spill_slot(slot, ext)
+        with tracing.span("engine.decode", rids=(r.rid,)) as sp:
+            ext = self._assemble_spilled(slot)
+            tok = jnp.asarray([r.generated[-1]], jnp.int32)
+            pos = jnp.asarray([r.context_len - 1], jnp.int32)
+            sp.set(kv_live_tokens=r.context_len,
+                   kv_read_tokens=_kv_read_tokens(ext))
+            logits, ext = self._decode(self.params, ext, tok, pos,
+                                       sp=self.par_layout.sp)
+            nxt = _sample(logits, 0.0, self.rng)
+            with tracing.span("engine.sync", kind="decode"):
+                t = int(nxt[0])
+            if r.temperature > 0:
+                sub_rng = jax.random.fold_in(
+                    jax.random.fold_in(self.rng, r.rid), r.context_len)
+                t = int(_sample(logits[0][None], r.temperature,
+                                sub_rng)[0])
+            self.spill_slot(slot, ext)
         r.generated.append(t)
         if (len(r.generated) >= r.max_new_tokens
                 or (r.eos_id is not None and t == r.eos_id)
@@ -1528,59 +1535,101 @@ class Engine:
         included, thanks to layer-coherent schedule steps and boundary
         ``device_put`` of activations — so a transforming engine never
         emits a zero-token step while it holds decodable work."""
-        emitted = 0
-        decode_emitted = 0
-        if self._session is not None:
-            s = self._session
-            # complete the transfers dispatched last iteration (they
-            # overlapped that iteration's decode), then issue the next
-            # step's transfers so THIS decode hides them
-            s.complete_step()
-            if s.done:
-                self._finish_transform()
-            else:
-                # stage the next step and prime ONE layer group; the
-                # decode iteration's layer walk streams the rest
-                # (``on_decode_layer``: layer L's weights move while
-                # layer L-1 computes), with a drain after the walk for
-                # whatever the walk couldn't safely overlap
-                s.dispatch_step_begin()
-                s.dispatch_step_advance()
-        in_session = self._session is not None
-        cross_session = in_session and self._session_cross
-        # policy-driven prefill work (admissions + chunk advancement);
-        # chunked prefills keep advancing during sessions via the
-        # per-layer path, whole-prompt prefills wait for the drain
-        emitted += self._prefill_step()
+        with tracing.span("engine.step", iid=self.iid):
+            emitted = 0
+            decode_emitted = 0
+            if self._session is not None:
+                s = self._session
+                # complete the transfers dispatched last iteration (they
+                # overlapped that iteration's decode), then issue the next
+                # step's transfers so THIS decode hides them
+                with tracing.span("engine.session") as sp:
+                    self._complete_session_step(sp)
+                    if self._session is not None:
+                        # stage the next step and prime ONE layer group; the
+                        # decode iteration's layer walk streams the rest
+                        # (``on_decode_layer``: layer L's weights move while
+                        # layer L-1 computes), with a drain after the walk
+                        # for whatever the walk couldn't safely overlap
+                        s.dispatch_step_begin()
+                        s.dispatch_step_advance()
+            in_session = self._session is not None
+            cross_session = in_session and self._session_cross
+            # policy-driven prefill work (admissions + chunk advancement);
+            # chunked prefills keep advancing during sessions via the
+            # per-layer path, whole-prompt prefills wait for the drain
+            emitted += self._prefill_step()
 
-        active = [r for r in self.slots
-                  if r is not None and r.state == State.DECODE]
-        # spilled slots past the local ceiling decode one-by-one on the
-        # extended (local + host pages) view; everything else stays on
-        # the batched fast path
-        lcap = self._local_page_cap() if self._spills else 0
-        ext_active = [r for r in active
-                      if r.slot in self._spills
-                      and r.context_len - 1 >= lcap]
-        ext_slots = {r.slot for r in ext_active}
-        batch_active = [r for r in active if r.slot not in ext_slots]
-        # the batched decode appends masked filler at EVERY row's cursor
-        # — including spilled rows whose local pages are completely full
-        # of real prefix (cursor % capacity would land ON it).  Save
-        # those rows' batch-1 views and restore them after the batch.
-        protect = [s for s in self._spills if s not in ext_slots
-                   and self.slots[s] is not None] if batch_active else []
-        saved = {s: self._extract_slot_cache(s) for s in protect}
-        if batch_active:
+            active = [r for r in self.slots
+                      if r is not None and r.state == State.DECODE]
+            # spilled slots past the local ceiling decode one-by-one on the
+            # extended (local + host pages) view; everything else stays on
+            # the batched fast path
+            lcap = self._local_page_cap() if self._spills else 0
+            ext_active = [r for r in active
+                          if r.slot in self._spills
+                          and r.context_len - 1 >= lcap]
+            ext_slots = {r.slot for r in ext_active}
+            batch_active = [r for r in active if r.slot not in ext_slots]
+            # the batched decode appends masked filler at EVERY row's cursor
+            # — including spilled rows whose local pages are completely full
+            # of real prefix (cursor % capacity would land ON it).  Save
+            # those rows' batch-1 views and restore them after the batch.
+            protect = [s for s in self._spills if s not in ext_slots
+                       and self.slots[s] is not None] if batch_active else []
+            saved = {s: self._extract_slot_cache(s) for s in protect}
+            if batch_active:
+                n = self._decode_batch(batch_active)
+                emitted += n
+                decode_emitted += n
+            for s, sub in saved.items():
+                self._adopt_slot_cache(sub, s, 0)
+            for r in ext_active:
+                n = self._decode_spilled(r)
+                emitted += n
+                decode_emitted += n
+            for s in [s for s in self._spills if self.slots[s] is None]:
+                self._release_spill(s)
+            # the final schedule step's transfers overlapped this decode;
+            # complete them now so the session drains within this iteration
+            if self._session is not None and self._session.all_dispatched:
+                with tracing.span("engine.session") as sp:
+                    self._complete_session_step(sp)
+            self.steps += 1
+            return {"active": len(active), "waiting": len(self.waiting),
+                    "emitted": emitted, "decode_emitted": decode_emitted,
+                    "transforming": int(in_session),
+                    "cross_session": int(cross_session)}
+
+    def _complete_session_step(self, sp) -> None:
+        """Block on the session step dispatched last, record its exposed
+        time on ``sp``, and close the session once its schedule ran
+        out."""
+        rep = self._session.complete_step()
+        if rep is not None:
+            sp.set(blocked_s=rep.blocked_s)
+        if self._session.done:
+            self._finish_transform()
+
+    def _decode_batch(self, batch_active: List[ServeRequest]) -> int:
+        """One batched decode step for the rows of ``batch_active``;
+        returns the tokens emitted."""
+        with tracing.span("engine.decode",
+                          rids=tuple(r.rid for r in batch_active)) as sp:
             tokens = np.zeros((self.max_batch,), np.int32)
             positions = np.zeros((self.max_batch,), np.int32)
             for r in batch_active:
                 tokens[r.slot] = r.generated[-1]
                 positions[r.slot] = r.context_len - 1
+            caches = (self.caches if self._session is None
+                      else [layer["cache"] for layer in self._session.layers])
+            sp.set(kv_live_tokens=sum(r.context_len for r in batch_active),
+                   kv_read_tokens=_kv_read_tokens(caches))
             logits = self._decode_dispatch(
                 jnp.asarray(tokens), jnp.asarray(positions))
             nxt = _sample(logits, 0.0, self.rng)  # greedy batch default
-            nxt = np.asarray(nxt)
+            with tracing.span("engine.sync", kind="decode"):
+                nxt = np.asarray(nxt)
             for r in batch_active:
                 tok = int(nxt[r.slot])
                 if r.temperature > 0:
@@ -1589,8 +1638,6 @@ class Engine:
                     tok = int(_sample(logits[r.slot][None], r.temperature,
                                       sub_rng)[0])
                 r.generated.append(tok)
-                emitted += 1
-                decode_emitted += 1
                 if (len(r.generated) >= r.max_new_tokens
                         or (r.eos_id is not None and tok == r.eos_id)
                         or r.context_len >= self._slot_ceiling(r.slot)):
@@ -1598,25 +1645,7 @@ class Engine:
                     r.t_done = self._clock()
                     self.slots[r.slot] = None
             self._pin_prefill_cursors()
-        for s, sub in saved.items():
-            self._adopt_slot_cache(sub, s, 0)
-        for r in ext_active:
-            n = self._decode_spilled(r)
-            emitted += n
-            decode_emitted += n
-        for s in [s for s in self._spills if self.slots[s] is None]:
-            self._release_spill(s)
-        # the final schedule step's transfers overlapped this decode;
-        # complete them now so the session drains within this iteration
-        if self._session is not None and self._session.all_dispatched:
-            self._session.complete_step()
-            if self._session.done:
-                self._finish_transform()
-        self.steps += 1
-        return {"active": len(active), "waiting": len(self.waiting),
-                "emitted": emitted, "decode_emitted": decode_emitted,
-                "transforming": int(in_session),
-                "cross_session": int(cross_session)}
+        return len(batch_active)
 
     def _decode_dispatch(self, tokens: jax.Array,
                          positions: jax.Array) -> jax.Array:
@@ -1648,6 +1677,18 @@ class Engine:
                 return
             self.step()
         raise RuntimeError("engine did not drain")
+
+
+def _kv_read_tokens(caches) -> int:
+    """KV tokens one decode step's attention reads per layer from
+    ``caches``: every row's whole reservation, live or not (a pool's
+    ``positions`` table is rows x capacity), for its widest pool."""
+    from repro.paged.pool import PagedState
+
+    pools = jax.tree.leaves(caches,
+                            is_leaf=lambda x: isinstance(x, PagedState))
+    return max((x.positions.shape[-2] * x.positions.shape[-1]
+                for x in pools if isinstance(x, PagedState)), default=0)
 
 
 def _batch_axis(dst, src) -> int:

@@ -313,8 +313,11 @@ def test_chunk_path_jit_cache_hits_after_warmup():
     """ISSUE-5 satellite: the chunked-prefill hot path is jitted with a
     per-(batch, chunk_len) compile cache — after the first request warms
     the chunk shapes, later requests with the same chunk plan HIT the
-    cache instead of retracing."""
+    cache instead of retracing.  The recorder's ``compiles`` on each
+    ``engine.chunk`` span (its own and its children's) say which chunk
+    compiled."""
     from repro.core.scheduler import PrefillPolicy
+    from repro.serving import tracing
     from repro.serving.request import ServeRequest
 
     cfg = _cfg()
@@ -323,22 +326,25 @@ def test_chunk_path_jit_cache_hits_after_warmup():
     eng = _mk_engine(pol)
     mk = lambda rid: ServeRequest(rid=rid, prompt=rng.integers(
         0, cfg.vocab_size, size=56).tolist(), max_new_tokens=2)
+
+    def chunk_compiles(rid):
+        return [n for s, n in tracing.rolled_up(
+            tracing.RECORDER.spans(), "engine.chunk", "compiles")
+            if s.attrs["rid"] == rid]
+
+    tracing.RECORDER.clear()
     eng.submit(mk(0))
     eng.run_until_done(500)
-    warm_misses = eng.chunk_cache_misses
-    assert warm_misses > 0                     # the [16, 16, 16, 8] plan
-    # 3rd 16-token chunk hits (the 1st compiles the static first-chunk
-    # variant, the 2nd the continuation variant)
-    assert eng.chunk_cache_hits >= 1
+    first = chunk_compiles(0)
+    assert len(first) == 4                     # the [16, 16, 16, 8] plan
+    # the 1st chunk compiles the static first-chunk variant, the 2nd
+    # the continuation variant; the 3rd 16-token chunk hits
+    assert first[0] > 0 and first[1] > 0
+    assert first[2] == 0
     eng.submit(mk(1))
     eng.run_until_done(500)
     # the second request's chunks are all warm shapes: no new traces
-    assert eng.chunk_cache_misses == warm_misses
-    assert eng.chunk_cache_hits >= warm_misses
-    # the observability counters mirror jit's real trace cache
-    if hasattr(eng._prefill_chunk_jit, "_cache_size"):
-        assert eng._prefill_chunk_jit._cache_size() == len(
-            eng._chunk_keys)
+    assert chunk_compiles(1) == [0, 0, 0, 0]
 
 
 def test_queue_delay_in_metrics_schema():
