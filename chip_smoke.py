@@ -223,7 +223,7 @@ def serve_one_chip(cfg, devices, *, max_seq: int, max_batch: int,
                             prefill_budget=CHUNK,
                             rng=jax.random.PRNGKey(SEED))
     eng = cluster.engines[0]
-    assert eng.fused_chunk_kernel, "the chunked prefill must take the kernel"
+    assert eng.pallas_kernels, "chunk prefill and decode must take the kernels"
     jax.block_until_ready(eng.params)
     print(f"[one-chip] setup {time.perf_counter() - t0:.1f} s: "
           f"params {tree_bytes(eng.params)} B, "

@@ -9,15 +9,20 @@ import jax.numpy as jnp
 
 
 def paged_attention_ref(q: jax.Array, pool: jax.Array,
-                        page_table: jax.Array, seq_lens: jax.Array
-                        ) -> jax.Array:
+                        page_table: jax.Array, seq_lens: jax.Array,
+                        kv_positions=None, q_positions=None,
+                        window: int = 0) -> jax.Array:
     """Decode attention over a header-centric paged KV pool.
 
-    q:          (B, Hq, dh)
-    pool:       (NP, kvs, 2, P, dh)   canonical header-centric layout
-    page_table: (B, max_pages) int32
-    seq_lens:   (B,) int32 — valid tokens per sequence (non-ring cache)
-    returns     (B, Hq, dh)
+    q:            (B, Hq, dh)
+    pool:         (NP, kvs, 2, P, dh)   canonical header-centric layout
+    page_table:   (B, max_pages) int32
+    seq_lens:     (B,) int32 — without positions: the first ``seq_len``
+                  slots are the keys (non-ring cache)
+    kv_positions: (B, max_pages * P) slot positions (-1 = empty) and
+    q_positions:  (B,) query positions: keys are ``0 <= pos <= q_pos``
+                  (and ``pos > q_pos - window`` when ``window > 0``)
+    returns       (B, Hq, dh)
     """
     B, Hq, dh = q.shape
     NP, kvs, _, P, _ = pool.shape
@@ -29,8 +34,15 @@ def paged_attention_ref(q: jax.Array, pool: jax.Array,
     v = pages[:, :, :, 1].transpose(0, 2, 1, 3, 4).reshape(B, kvs, n * P, dh)
     qg = q.reshape(B, kvs, rep, dh).astype(jnp.float32) * scale
     s = jnp.einsum("bhrd,bhtd->bhrt", qg, k.astype(jnp.float32))
-    pos = jnp.arange(n * P)[None, None, None, :]
-    mask = pos < seq_lens[:, None, None, None]
+    if kv_positions is None:
+        pos = jnp.arange(n * P)[None, None, None, :]
+        mask = pos < seq_lens[:, None, None, None]
+    else:
+        pos = kv_positions[:, None, None, :]
+        qp = q_positions[:, None, None, None]
+        mask = (pos >= 0) & (pos <= qp)
+        if window > 0:
+            mask = mask & (pos > qp - window)
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhrt,bhtd->bhrd", p, v.astype(jnp.float32))

@@ -21,6 +21,7 @@ from repro.configs.base import (ATTN, MLSTM, MOE, RGLRU, SLIDING, SLSTM,
                                 ModelConfig)
 from repro.core.padding import PaddingPlan
 from repro.kernels import chunk_prefill as CP
+from repro.kernels import paged_attention as PA
 from repro.models import layers as Lyr
 from repro.models import shardhints
 from repro.paged import pool as pp
@@ -173,18 +174,31 @@ def attention_decode(p: Params, x: jax.Array, cfg: ModelConfig,
                      cache: pp.PagedState, window: int = 0,
                      layout: str = "header_centric",
                      identity_pages: bool = False,
-                     sp: int = 1
+                     use_kernel: bool = False,
+                     sp: int = 1, mesh=None
                      ) -> Tuple[jax.Array, pp.PagedState]:
     """One-token decode. x: (B,1,d); positions: (B,1) global positions.
     ``sp > 1`` runs the sequence-parallel page walk: each sp shard walks
     its slice of the slot's pages and the partial softmax states combine
-    across the sp axis (see ``Lyr.paged_decode_attention``)."""
+    across the sp axis (see ``Lyr.paged_decode_attention``).
+    use_kernel=True (``sp == 1``): the Pallas paged-attention kernel
+    reads the slot's live pages in place from the pool
+    (``kernels.paged_attention``); ``mesh`` is the instance mesh the
+    cache lives on, over which the kernel runs per kv-head and replica
+    shard.  Sequence-parallel meshes keep the jnp paths."""
     B, _, d = x.shape
     dh = cfg.resolved_head_dim
     q, k, v = _project_qkv(p, x, cfg, plan, positions)
     cache = pp.append_token(cache, k[:, 0], v[:, 0], layout,
                             identity_pages=identity_pages)
-    if identity_pages:
+    if (use_kernel and sp == 1 and cache.pool.ndim == 5
+            and (mesh is None or mesh.shape["sp"] == 1)):
+        attn = PA.paged_attention_sharded(
+            mesh, q[:, 0], pp.canonical(cache.pool, layout),
+            cache.page_table, cache.seq_lens, cache.positions,
+            positions[:, 0], window=window)
+        attn = attn[:, None]
+    elif identity_pages:
         # §Perf iteration 4: walk the header-centric pool in place (jnp
         # mirror of the Pallas kernel) — no transposed K/V copies.
         pool_c = pp.canonical(cache.pool, layout)
@@ -537,15 +551,18 @@ def apply_block_decode(kind: str, p: Params, cfg: ModelConfig,
                        positions: jax.Array, cache,
                        layout: str = "header_centric",
                        identity_pages: bool = False,
-                       sp: int = 1):
+                       use_kernel: bool = False,
+                       sp: int = 1, mesh=None):
     """Single-token decode for one block. x: (B,1,d). cache is the block's
-    state: PagedState for attention kinds, dict for recurrent kinds."""
+    state: PagedState for attention kinds, dict for recurrent kinds.
+    ``use_kernel`` / ``mesh``: see ``attention_decode``."""
     if kind in (ATTN, SLIDING, MOE):
         h = Lyr.rmsnorm(x, p["ln1"], cfg.norm_eps)
         attn_out, cache = attention_decode(
             p["attn"], h, cfg, plan, positions, cache,
             window=_window_of(kind, cfg), layout=layout,
-            identity_pages=identity_pages, sp=sp)
+            identity_pages=identity_pages, use_kernel=use_kernel,
+            sp=sp, mesh=mesh)
         x = x + attn_out
         h = Lyr.rmsnorm(x, p["ln2"], cfg.norm_eps)
         if kind == MOE:

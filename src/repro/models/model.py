@@ -457,12 +457,16 @@ def decode_step(params, cfg: ModelConfig, plan: PaddingPlan,
                 caches: Dict[str, Any], tokens: jax.Array,
                 positions: jax.Array, layout: str = "header_centric",
                 unroll: bool = False, identity_pages: bool = False,
-                sp: int = 1
+                use_kernel: bool = False, sp: int = 1, mesh=None
                 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """tokens: (B,) int32; positions: (B,) global positions.  ``sp`` is
     the sequence-parallel shard count of the engine's current layout
     (``Layout.sp``): >1 computes attention in the per-shard-partials +
-    cross-shard-combine form matching the pool's page sharding."""
+    cross-shard-combine form matching the pool's page sharding.
+    ``use_kernel`` runs decode attention through the Pallas paged-
+    attention kernel over each slot's live pages (``sp == 1``); ``mesh``
+    is the instance mesh the caches live on (see
+    ``blocks.attention_decode``)."""
     unit = pattern_unit(cfg)
     G, R = group_counts(cfg)
     x = params["embed"][tokens][:, None, :]          # (B,1,d)
@@ -474,7 +478,8 @@ def decode_step(params, cfg: ModelConfig, plan: PaddingPlan,
         for i, kind in enumerate(unit):
             xc, gcaches[i] = B.apply_block_decode(
                 kind, gparams[i], cfg, plan, xc, pos2, gcaches[i], layout,
-                identity_pages=identity_pages, sp=sp)
+                identity_pages=identity_pages, use_kernel=use_kernel,
+                sp=sp, mesh=mesh)
         if cfg.encoder is not None:
             cp, (ck, cv) = xs[-2], xs[-1]
             xc = xc + cross_attention(cp, xc, cfg, plan, ck, cv)
@@ -489,7 +494,8 @@ def decode_step(params, cfg: ModelConfig, plan: PaddingPlan,
     for i in range(R):
         x, c = B.apply_block_decode(unit[i], params["rem"][i], cfg, plan, x,
                                     pos2, caches["rem"][i], layout,
-                                    identity_pages=identity_pages, sp=sp)
+                                    identity_pages=identity_pages,
+                                    use_kernel=use_kernel, sp=sp, mesh=mesh)
         new_rem.append(c)
 
     out = {"groups": list(new_group_caches), "rem": new_rem}
@@ -619,11 +625,12 @@ def _boundary_put(x: jax.Array, mesh, cur: Optional[frozenset]
 # by op instead, every Pallas call (and every shard_map around one)
 # would be traced, lowered and compiled anew on each call.
 @partial(jax.jit, static_argnames=("kind", "cfg", "plan", "layout",
-                                   "identity_pages"))
+                                   "identity_pages", "use_kernel", "mesh"))
 def _block_decode(p, x, positions, cache, *, kind, cfg, plan, layout,
-                  identity_pages):
+                  identity_pages, use_kernel, mesh):
     return B.apply_block_decode(kind, p, cfg, plan, x, positions, cache,
-                                layout, identity_pages=identity_pages)
+                                layout, identity_pages=identity_pages,
+                                use_kernel=use_kernel, mesh=mesh)
 
 
 @partial(jax.jit, static_argnames=("kind", "cfg", "plan", "layout",
@@ -643,7 +650,8 @@ def decode_step_layers(layers: List[Dict[str, Any]],
                        positions: jax.Array,
                        layout: str = "header_centric",
                        identity_pages: bool = False,
-                       static_mesh=None, on_layer=None
+                       static_mesh=None, on_layer=None,
+                       use_kernel: bool = False
                        ) -> Tuple[jax.Array, List[Dict[str, Any]]]:
     """One decode step over per-layer state; numerically identical to
     ``decode_step`` on the restacked equivalents.
@@ -652,7 +660,9 @@ def decode_step_layers(layers: List[Dict[str, Any]],
     layer coherently on one); layer dicts then carry a ``"mesh"`` tag
     and ``static_mesh`` locates the embed/head params — activations are
     ``device_put`` once per assembly boundary, so a single decode step
-    runs across the mixed state without stalling.
+    runs across the mixed state without stalling.  With ``use_kernel``
+    each layer's decode attention runs the paged-attention kernel on
+    that layer's mesh.
 
     ``on_layer(i)`` (optional) is called after layer ``i``'s compute has
     been enqueued — the hook a transform session uses to stream the next
@@ -665,7 +675,9 @@ def decode_step_layers(layers: List[Dict[str, Any]],
         x, cur = _boundary_put(x, layer.get("mesh"), cur)
         x, c = _block_decode(layer["params"], x, pos2, layer["cache"],
                              kind=layer["kind"], cfg=cfg, plan=plan,
-                             layout=layout, identity_pages=identity_pages)
+                             layout=layout, identity_pages=identity_pages,
+                             use_kernel=use_kernel,
+                             mesh=layer.get("mesh") if use_kernel else None)
         new_layers.append({**layer, "cache": c})
         if on_layer is not None:
             on_layer(i)

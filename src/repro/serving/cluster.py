@@ -85,7 +85,7 @@ class ClusterEngine:
                  dwell_steps: int = 8, layout: str = "header_centric",
                  transform_attn: bool = True,
                  prefill_policy: Optional[PrefillPolicy] = None,
-                 clock=None, fused_chunk_kernel: Optional[bool] = None):
+                 clock=None, pallas_kernels: Optional[bool] = None):
         if n_instances < 1 or len(devices) < n_instances:
             raise ValueError(f"{n_instances} instances need at least "
                              f"{n_instances} of {len(devices)} devices")
@@ -116,7 +116,7 @@ class ClusterEngine:
                    layout=layout, devices=list(devices[k * W:(k + 1) * W]),
                    transform_attn=transform_attn, iid=k, plan=self.plan,
                    prefill_policy=self.prefill_policy, clock=self._clock,
-                   fused_chunk_kernel=fused_chunk_kernel)
+                   pallas_kernels=pallas_kernels)
             for k in range(n_instances)]
         if scheduler is None:
             base = self.engines[0].max_seq_at(1)

@@ -83,7 +83,7 @@ class Engine:
                  plan: Optional[PaddingPlan] = None,
                  prefill_policy: Optional[PrefillPolicy] = None,
                  clock=None,
-                 fused_chunk_kernel: Optional[bool] = None):
+                 pallas_kernels: Optional[bool] = None):
         """``plan`` overrides the padding plan; a cluster whose engines
         may MERGE must pass one built for the full device-pool width so
         weight shard boundaries stay page-aligned at every reachable TP
@@ -96,11 +96,13 @@ class Engine:
         deliberately stay on the wall clock — they time real device
         work, not the serving schedule.
 
-        ``fused_chunk_kernel`` routes chunk prefills through the fused
+        ``pallas_kernels`` routes chunk prefills through the fused
         Pallas paged-attention + scatter kernel
-        (``kernels.chunk_prefill``).  Default (None) enables it on real
-        TPU backends only: off-TPU the kernel runs in interpret mode —
-        correct but slow — and the jnp path keeps CI streams
+        (``kernels.chunk_prefill``) and decode attention through the
+        Pallas paged-attention kernel over each slot's live pages
+        (``kernels.paged_attention``).  Default (None) enables both on
+        real TPU backends only: off-TPU the kernels run in interpret
+        mode — correct but slow — and the jnp paths keep CI streams
         bit-identical to the pre-kernel engine."""
         self.cfg = cfg
         self._clock = clock if clock is not None else time.monotonic
@@ -177,9 +179,9 @@ class Engine:
         # — provided each chunk fits the smallest ring (``_begin_prefill``
         # splits the policy's chunks to the min attention capacity).
         self._can_chunk = cfg.encoder is None and cfg.vision is None
-        self.fused_chunk_kernel = (
-            jax.default_backend() == "tpu" if fused_chunk_kernel is None
-            else bool(fused_chunk_kernel))
+        self.pallas_kernels = (
+            jax.default_backend() == "tpu" if pallas_kernels is None
+            else bool(pallas_kernels))
         self.steps = 0
         self.tp = 1
         self.par_layout = Layout.of(1)
@@ -217,11 +219,16 @@ class Engine:
         # ``par_layout``) is STATIC: each layout's decode/chunk trace
         # folds the sp shards into the batch dimension and combines
         # partial softmax states across them (elastic sequence
-        # parallelism) — a layout change simply keys a fresh trace
-        @partial(jax.jit, static_argnames=("sp",))
-        def _decode(params, caches, tokens, positions, sp=1):
+        # parallelism) — a layout change simply keys a fresh trace.
+        # ``mesh`` is static too: the paged-attention kernel runs per
+        # shard of it (``kernels.paged_attention_sharded``)
+        use_kernel = self.pallas_kernels
+
+        @partial(jax.jit, static_argnames=("sp", "mesh"))
+        def _decode(params, caches, tokens, positions, sp=1, mesh=None):
             return M.decode_step(params, cfgc, planc, caches, tokens,
-                                 positions, layoutc, sp=sp)
+                                 positions, layoutc, use_kernel=use_kernel,
+                                 sp=sp, mesh=mesh)
 
         self._decode = _decode
 
@@ -233,8 +240,6 @@ class Engine:
         # ``engine.chunk`` span (``serving.tracing``).  The slot
         # views are extracted with fresh identity page tables, so the
         # GSPMD-local identity gather/scatter path is always valid here.
-        use_kernel_c = self.fused_chunk_kernel
-
         @partial(jax.jit, static_argnames=("first_chunk", "sp", "mesh"))
         def _chunk(params, tokens, start_pos, sub, first_chunk=False,
                    sp=1, mesh=None):
@@ -242,7 +247,7 @@ class Engine:
                                    start_pos, sub, layoutc,
                                    first_chunk=first_chunk,
                                    identity_pages=True,
-                                   use_kernel=use_kernel_c, sp=sp,
+                                   use_kernel=use_kernel, sp=sp,
                                    mesh=mesh)
 
         self._prefill_chunk_jit = _chunk
@@ -1005,7 +1010,7 @@ class Engine:
                 s.layers, s.static, self.cfg, self.plan, tokens, start_a,
                 subs, self.layout, static_mesh=s.static_mesh,
                 first_chunk=start == 0, identity_pages=True,
-                use_kernel=self.fused_chunk_kernel)
+                use_kernel=self.pallas_kernels)
         with tracing.span("engine.chunk.adopt"):
             for layer, sub in zip(s.layers, new_subs):
                 layer["cache"] = self._adopt_slot_tree(layer["cache"],
@@ -1498,9 +1503,11 @@ class Engine:
             tok = jnp.asarray([r.generated[-1]], jnp.int32)
             pos = jnp.asarray([r.context_len - 1], jnp.int32)
             sp.set(kv_live_tokens=r.context_len,
-                   kv_read_tokens=_kv_read_tokens(ext))
+                   kv_read_tokens=self._kv_read_tokens(
+                       ext, [r.context_len]))
             logits, ext = self._decode(self.params, ext, tok, pos,
-                                       sp=self.par_layout.sp)
+                                       sp=self.par_layout.sp,
+                                       mesh=self.mesh)
             nxt = _sample(logits, 0.0, self.rng)
             with tracing.span("engine.sync", kind="decode"):
                 t = int(nxt[0])
@@ -1574,9 +1581,12 @@ class Engine:
             # the batched decode appends masked filler at EVERY row's cursor
             # — including spilled rows whose local pages are completely full
             # of real prefix (cursor % capacity would land ON it).  Save
-            # those rows' batch-1 views and restore them after the batch.
-            protect = [s for s in self._spills if s not in ext_slots
-                       and self.slots[s] is not None] if batch_active else []
+            # the views of the spilled rows the batch does not decode and
+            # restore them after it (a spilled row the batch decodes keeps
+            # its new token).
+            batch_slots = {r.slot for r in batch_active}
+            protect = [s for s in self._spills
+                       if s not in batch_slots] if batch_active else []
             saved = {s: self._extract_slot_cache(s) for s in protect}
             if batch_active:
                 n = self._decode_batch(batch_active)
@@ -1623,8 +1633,9 @@ class Engine:
                 positions[r.slot] = r.context_len - 1
             caches = (self.caches if self._session is None
                       else [layer["cache"] for layer in self._session.layers])
-            sp.set(kv_live_tokens=sum(r.context_len for r in batch_active),
-                   kv_read_tokens=_kv_read_tokens(caches))
+            contexts = [r.context_len for r in batch_active]
+            sp.set(kv_live_tokens=sum(contexts),
+                   kv_read_tokens=self._kv_read_tokens(caches, contexts))
             logits = self._decode_dispatch(
                 jnp.asarray(tokens), jnp.asarray(positions))
             nxt = _sample(logits, 0.0, self.rng)  # greedy batch default
@@ -1659,7 +1670,8 @@ class Engine:
             logits, new_layers = M.decode_step_layers(
                 s.layers, s.static, self.cfg, self.plan, tokens,
                 positions, self.layout, static_mesh=s.static_mesh,
-                on_layer=s.on_decode_layer)
+                on_layer=s.on_decode_layer,
+                use_kernel=self.pallas_kernels)
             s.layers = new_layers
             # groups the walk couldn't overlap (their layer was already
             # walked) dispatch now, against the walk's updated layers
@@ -1667,8 +1679,40 @@ class Engine:
             return logits
         logits, self.caches = self._decode(self.params, self.caches,
                                            tokens, positions,
-                                           sp=self.par_layout.sp)
+                                           sp=self.par_layout.sp,
+                                           mesh=self.mesh)
         return logits
+
+    def _kv_read_tokens(self, caches, contexts: List[int]) -> int:
+        """KV tokens one decode step's attention reads per layer from
+        ``caches`` for its widest pool, ``contexts`` being the decoding
+        rows' context lengths.  The jnp paths read every row's whole
+        reservation, live or not (rows x capacity of the pool's
+        ``positions``).  The paged-attention kernel reads each row's
+        live pages: a decoding row's context rounded up to whole pages
+        (every page once it passes a ring's capacity), one page of any
+        other row (its query sits at position 0)."""
+        from repro.paged.pool import PagedState
+
+        if self._session is None:
+            kernel = self.par_layout.sp == 1
+        else:
+            kernel = all(l.get("mesh") is None or l["mesh"].shape["sp"] == 1
+                         for l in self._session.layers)
+        P = self.page_tokens
+
+        def read(x):
+            rows, cap = x.positions.shape[-2:]
+            if not (self.pallas_kernels and kernel):
+                return rows * cap
+            n = cap // P
+            pages = sum(min(-(-c // P), n) for c in contexts)
+            return (pages + rows - len(contexts)) * P
+
+        pools = jax.tree.leaves(caches,
+                                is_leaf=lambda x: isinstance(x, PagedState))
+        return max((read(x) for x in pools if isinstance(x, PagedState)),
+                   default=0)
 
     def run_until_done(self, max_steps: int = 10_000) -> None:
         for _ in range(max_steps):
@@ -1677,18 +1721,6 @@ class Engine:
                 return
             self.step()
         raise RuntimeError("engine did not drain")
-
-
-def _kv_read_tokens(caches) -> int:
-    """KV tokens one decode step's attention reads per layer from
-    ``caches``: every row's whole reservation, live or not (a pool's
-    ``positions`` table is rows x capacity), for its widest pool."""
-    from repro.paged.pool import PagedState
-
-    pools = jax.tree.leaves(caches,
-                            is_leaf=lambda x: isinstance(x, PagedState))
-    return max((x.positions.shape[-2] * x.positions.shape[-1]
-                for x in pools if isinstance(x, PagedState)), default=0)
 
 
 def _batch_axis(dst, src) -> int:

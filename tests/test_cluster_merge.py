@@ -232,7 +232,7 @@ def test_merge_with_fused_chunk_kernel_mid_session():
         cluster = ClusterEngine(cfg, devs, n_instances=4, max_batch=4,
                                 max_seq=Q, page_tokens=PAGE,
                                 prefill_policy=policy,
-                                fused_chunk_kernel=True)
+                                pallas_kernels=True)
         target_chunks = []
         for e in cluster.engines:
             run_layers = e._run_chunk_layers
@@ -270,7 +270,7 @@ def test_merge_with_fused_chunk_kernel_mid_session():
         ref = Engine(cfg, params=cluster._params_src, max_batch=4,
                      max_seq=4 * Q, page_tokens=PAGE, devices=devs,
                      plan=cluster.plan, prefill_policy=policy,
-                     fused_chunk_kernel=True)
+                     pallas_kernels=True)
         ref.transform(4)
         while ref.transforming:
             ref.step()
