@@ -63,23 +63,6 @@ class CompileClock:
         return (self.programs, self.seconds)
 
 
-def program_config(model: Dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=model["name"], arch_type="dense",
-        num_layers=model["num_hidden_layers"],
-        d_model=model["hidden_size"],
-        num_heads=model["num_attention_heads"],
-        num_kv_heads=model["num_key_value_heads"],
-        d_ff=model["intermediate_size"], vocab_size=model["vocab_size"],
-        head_dim=model["head_dim"], activation="swiglu",
-        tie_embeddings=model["tie_word_embeddings"],
-        rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]),
-        dtype=model["torch_dtype"])
-
-
 def name_chunk_kernel() -> None:
     """Trace the program's chunk-prefill kernel call inside a name scope,
     so its Mosaic call is named ``CHUNK_KERNEL`` in the compiled program
@@ -169,14 +152,15 @@ def build(cell: Dict, model: Dict, seed: int, devices, clk: CompileClock):
     from repro.serving.cluster import ClusterEngine
 
     eng = cell["engine"]
-    cfg = program_config(model)
+    fam = spec.family(model)
+    cfg = fam.program_config(model)
     n_inst = eng["instances"]
     w = len(devices) // n_inst
     plan = make_plan(cfg, n_inst * w, mode="page")
     t0, c0 = clock(), clk.mark()
-    params = weights.for_program(seed, model, cfg, plan, device=devices[0])
+    params = fam.for_program(seed, model, cfg, plan, device=devices[0])
     jax.block_until_ready(params)
-    fp = weights.fingerprint_program(params, model)
+    fp = fam.fingerprint_program(params, model)
     t1, c1 = clock(), clk.mark()
     # launch.serve.build_cluster's cluster, given the benchmark's weights
     policy = PrefillPolicy(token_budget=eng["chunk_budget"],
@@ -354,19 +338,18 @@ def check(run: Dict, picks: List[int], model: Dict, seed: int, fp: Dict,
     below the reference's best, over the sampled requests.  With
     ``control`` the token compared at each position is the one the fp8
     control puts first, given the same prompt and served tokens."""
-    from chipbench import reference
-
-    w = weights.canonical(seed, model, device=device)
+    fam = spec.family(model)
+    w = fam.canonical(seed, model, device=device)
     same = weights.same_fingerprint(weights.fingerprint(w), fp)
     widest, n_tok = 0.0, 0
     for i in picks:
         q = run["reqs"][i]
         toks = list(q.prompt) + list(q.generated[:-1])
         rows = list(range(len(q.prompt) - 1, len(toks)))
-        ref = reference.logits(w, model, toks, rows, shape=shape)
+        ref = fam.logits(w, model, toks, rows, shape=shape)
         if control:
-            tok = reference.logits(w, model, toks, rows, quant=True,
-                                   shape=shape).argmax(axis=1)
+            tok = fam.logits(w, model, toks, rows, quant=True,
+                             shape=shape).argmax(axis=1)
         else:
             tok = np.asarray(q.generated)
         gap = ref.max(axis=1) - ref[np.arange(len(rows)), tok]

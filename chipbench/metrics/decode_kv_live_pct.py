@@ -1,9 +1,11 @@
 """Share of the KV that decode attention reads which belongs to live
 context: over the window's ``engine.decode`` spans, the summed
 ``kv_live_tokens`` (the decoded rows' context lengths) over the summed
-``kv_read_tokens`` (every row's whole reservation, empty rows included,
-from the cache shapes the program passes).  Program counters; nothing
-to read where the program records none."""
+``kv_read_tokens`` (what decode attention reads per layer: through the
+paged-attention kernel each decoding row's context rounded up to whole
+pages, every page once a ring wraps, and one page of every other row;
+on the program's jnp paths every row's whole reservation).  Program
+counters; nothing to read where the program records none."""
 from chipbench import program_spans as PS
 
 

@@ -1,25 +1,20 @@
-"""Plain float32 reference forward of a llama-style decoder, written from
-the published equations in ``jax.numpy``.  It imports nothing of the
-program under test.
+"""The parts of the plain float32 reference that every model family
+shares, written in ``jax.numpy`` from the published equations.  It
+imports nothing of the program under test.  A family module
+(``chipbench/families/``) writes its own layer equations with these and
+runs its forward through ``run``.
 
-    x_0 = E[t]
-    h   = RMSNorm(x) * g              RMSNorm(x) = x / sqrt(mean(x^2) + eps)
-    q, k, v = h Wq, h Wk, h Wv        per head, RoPE on q and k:
-        rot(x)_i = x_i cos(p w_i) - x_{i+D/2} sin(p w_i)       (i < D/2)
-        rot(x)_i = x_i cos(p w_j) + x_{i-D/2} sin(p w_j)       (j = i-D/2)
-        w_i = theta^(-2i/D)
-    a   = softmax(q k^T / sqrt(D) + causal mask) v,   kv heads shared by
-          num_attention_heads / num_key_value_heads query heads
-    x   = x + a Wo
-    x   = x + (silu(h' Wgate) * (h' Wup)) Wdown,     h' = RMSNorm(x) * g'
-    logits = RMSNorm(x) * g_final  E^T   (tied)  or  ... W_head (untied)
+    RMSNorm(x) * g = x / sqrt(mean(x^2) + eps) * (1 + deviation)
+    rot(x)_i = x_i cos(p w_i) - x_{i+D/2} sin(p w_i)       (i < D/2)
+    rot(x)_i = x_i cos(p w_j) + x_{i-D/2} sin(p w_j)       (j = i-D/2)
+    w_i = theta^(-2i/D)
+    attention = softmax(q k^T / sqrt(D) + causal mask) v
 
-Weights come from ``weights.canonical`` (norm weights as deviations
-from one: g = 1 + deviation).  Every matmul runs at float32 with
-``highest`` precision (a TPU otherwise runs float32 matmuls as one bf16
-pass).  Layers run one at a time over the whole sequence and attention
-in query blocks, so a long prompt fits beside the weights.  A caller
-pads every request of a cell to one shape, so one program serves them.
+Every matmul runs at float32 with ``highest`` precision (a TPU otherwise
+runs float32 matmuls as one bf16 pass).  A family runs its layers one at
+a time over the whole sequence and attention in query blocks, so a long
+prompt fits beside the weights.  A caller pads every request of a cell
+to one shape, so one program serves them.
 
 ``quant=True`` gives the control of the comparison: the same forward
 with every matmul in fp8 (e4m3), a step below the bfloat16 the
@@ -29,8 +24,7 @@ the embedding rows are rounded alike.
 """
 from __future__ import annotations
 
-from functools import partial
-from typing import Dict, Sequence, Tuple
+from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +32,6 @@ import numpy as np
 
 Q_BLOCK = 256
 FP8_MAX = 448.0      # largest finite float8_e4m3fn
-MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
 def rmsnorm(x, dev, eps):
@@ -94,53 +87,28 @@ def _attention(q, k, v, H, KV):
     return out.reshape(nb * Q_BLOCK, H, D)[:T]
 
 
-def _layer(x, w, pos, cfg, quant):
-    H, KV, D, eps, theta = cfg
-    T = x.shape[0]
-    h = rmsnorm(x, w["ln_attn"], eps)
-    q = rope(_mm(h, w["wq"], quant).reshape(T, H, D), pos, theta)
-    k = rope(_mm(h, w["wk"], quant).reshape(T, KV, D), pos, theta)
-    v = _mm(h, w["wv"], quant).reshape(T, KV, D)
-    a = _attention(q, k, v, H, KV).reshape(T, H * D)
-    x = x + _mm(a, w["wo"], quant)
-    h = rmsnorm(x, w["ln_mlp"], eps)
-    g = jax.nn.silu(_mm(h, w["w_gate"], quant)) * _mm(h, w["w_up"], quant)
-    return x + _mm(g, w["w_down"], quant)
-
-
-@partial(jax.jit, static_argnames=("cfg", "quant", "tied"))
-def _forward(weights, tokens, rows, cfg, quant, tied):
-    embed = weights["embed"]
+def embed(table, tokens, quant):
+    """Rows ``tokens`` of the embedding in float32; the control rounds
+    the table per row (for a tied model a row is one output column of
+    the head)."""
     if quant:
-        # the control rounds the embedding per row: for a tied model a
-        # row is one output column of the head
-        embed = _fp8(embed.T).T
-    x = jnp.take(embed, tokens, axis=0).astype(jnp.float32)
-    pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    stacked = {k: weights[k] for k in MATRICES + ("ln_attn", "ln_mlp")}
-    x, _ = jax.lax.scan(lambda x, w: (_layer(x, w, pos, cfg, quant), None),
-                        x, stacked)
-    head = weights["embed"].T if tied else weights["lm_head"]
-    return _mm(rmsnorm(x[rows], weights["ln_final"], cfg[3]), head, quant)
+        table = _fp8(table.T).T
+    return jnp.take(table, tokens, axis=0).astype(jnp.float32)
 
 
-def logits(weights: Dict, model: Dict, tokens: Sequence[int],
-           rows: Sequence[int], quant: bool = False,
-           shape: Tuple[int, int] = (0, 0)) -> np.ndarray:
-    """float32 next-token logits at positions ``rows`` of ``tokens``.
-    The sequence is padded at its end to ``shape[0]`` tokens and the
-    rows to ``shape[1]`` (one compiled program for every request of a
-    cell; causal attention: padding after a position cannot change
-    it)."""
-    cfg = (model["num_attention_heads"], model["num_key_value_heads"],
-           model["head_dim"], float(model["rms_norm_eps"]),
-           float(model["rope_theta"]))
+def run(forward: Callable, tokens: Sequence[int], rows: Sequence[int],
+        shape: Tuple[int, int] = (0, 0)) -> np.ndarray:
+    """float32 next-token logits at positions ``rows`` of ``tokens``:
+    ``forward(tokens, rows)`` on the sequence padded at its end to
+    ``shape[0]`` tokens and the rows to ``shape[1]`` (one compiled
+    program for every request of a cell; causal attention: padding
+    after a position cannot change it), every matmul at ``highest``
+    precision."""
     T, R = len(tokens), len(rows)
     toks = np.zeros(max(T, shape[0]), np.int32)
     toks[:T] = tokens
     rr = np.full(max(R, shape[1]), rows[-1], np.int32)
     rr[:R] = rows
     with jax.default_matmul_precision("highest"):
-        out = _forward(weights, jnp.asarray(toks), jnp.asarray(rr), cfg,
-                       quant, bool(model["tie_word_embeddings"]))
+        out = forward(jnp.asarray(toks), jnp.asarray(rr))
     return np.asarray(out, np.float32)[:R]

@@ -2,19 +2,29 @@
 
 A cell (``workloads/<cell>.json``) names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<mix>.json``);
-a per-layer metric is a reader in ``metrics/<metric>.py``.  Nothing here
-knows a cell, a model or a metric by name: a later change adds files,
-never edits these.
+a per-layer metric is a reader in ``metrics/<metric>.py``; a model
+family is a module in ``families/<architecture>.py``, named by the
+configuration's own ``architectures[0]``.  Nothing here knows a cell, a
+model, a family or a metric by name: a later change adds files, never
+edits these.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
+from types import ModuleType
 from typing import Callable, Dict, List
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+FAMILIES = os.path.join(HERE, "families")
+# what every family module gives (``families/__init__.py``)
+FAMILY_API = ("program_config", "tiny", "canonical", "for_program",
+              "fingerprint_program", "logits", "params", "weight_bytes",
+              "prefill_flops", "decode_flops", "decode_step_bytes",
+              "chunk_kernel")
 
 
 def _load(kind: str, name: str) -> Dict:
@@ -60,6 +70,30 @@ def reader(metric: str) -> Callable:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def family(model: Dict) -> ModuleType:
+    """The module of ``model``'s family, ``families/<architectures[0]>.py``.
+    A family that has no module is an error naming the file looked for,
+    never a default."""
+    return _family(os.path.join(FAMILIES,
+                                f"{model['architectures'][0]}.py"))
+
+
+@functools.lru_cache(maxsize=None)
+def _family(path: str) -> ModuleType:
+    # loaded once per path, so its jitted functions keep their programs
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no model family module {path}")
+    name = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_family_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in FAMILY_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"{path} lacks {missing}")
+    return mod
 
 
 def peaks(device_kind: str) -> Dict:

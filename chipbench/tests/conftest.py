@@ -15,11 +15,9 @@ for p in (ROOT, os.path.join(ROOT, "src")):
 
 
 def tiny_model(model):
-    """The configuration at a size the CPU serves in seconds (its
-    norms, RoPE, tying and dtype kept)."""
-    return dict(model, num_hidden_layers=2, hidden_size=128,
-                num_attention_heads=4, num_key_value_heads=4, head_dim=32,
-                intermediate_size=320, vocab_size=509)
+    """The configuration at its family's CPU test size."""
+    from chipbench import spec
+    return spec.family(model).tiny(model)
 
 
 def tiny_cell(name):

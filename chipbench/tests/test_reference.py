@@ -7,7 +7,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-from chipbench import harness, reference, spec, weights
+from chipbench import harness, spec, weights
 from conftest import tiny_cell, tiny_model
 
 
@@ -26,7 +26,7 @@ def _widest(w, model, reqs):
     for q in reqs:
         toks = q.prompt + q.generated[:-1]
         rows = list(range(len(q.prompt) - 1, len(toks)))
-        ref = reference.logits(w, model, toks, rows)
+        ref = spec.family(model).logits(w, model, toks, rows)
         out = max(out, float(np.max(ref.max(1) - ref[np.arange(len(rows)),
                                                      q.generated])))
     return out
@@ -39,9 +39,10 @@ def test_reference_matches_engine(name, cpu_devices):
     seed = 2 ** 32 + 77
     rng = np.random.default_rng(0)
     # 48 tokens: whole-prompt prefill; 200: chunked (64-token budget)
-    prompts = [rng.integers(0, 509, size=n).tolist() for n in (48, 200)]
+    prompts = [rng.integers(0, model["vocab_size"], size=n).tolist()
+               for n in (48, 200)]
     reqs, fp = _serve(cell, model, seed, prompts, 24, cpu_devices[:1])
-    w = weights.canonical(seed, model)
+    w = spec.family(model).canonical(seed, model)
     assert weights.same_fingerprint(weights.fingerprint(w), fp)
     assert _widest(w, model, reqs) < 1e-4
     wrong = dict(model, rope_theta=500.0)

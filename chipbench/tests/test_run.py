@@ -57,10 +57,11 @@ def test_control_is_not_correct(name, cpu_devices):
 def test_altered_token_is_not_correct(name, cpu_devices, monkeypatch):
     from repro.serving import engine as E
     sample = E._sample
+    vocab = tiny_model(spec.cell(name)["model"])["vocab_size"]
 
     def altered(logits, temperature, rng):
         # every sampled token moved one id up where it is produced
-        return (sample(logits, temperature, rng) + 1) % 509
+        return (sample(logits, temperature, rng) + 1) % vocab
 
     monkeypatch.setattr(E, "_sample", altered)
     res = _run(name, cpu_devices)["result"]
